@@ -1,6 +1,7 @@
-"""Kernel micro-benchmarks: pallas (interpret) vs jnp reference wall time.
+"""Kernel micro-benchmarks: pallas vs jnp reference wall time.
 
-On the CPU container interpret-mode timings are NOT TPU-indicative — the
+The kernels run compiled on a TPU and interpreted on the CPU; CPU
+(interpret-mode) timings are NOT TPU-indicative — the
 point of these rows is regression tracking of the wrapper overheads and
 a correctness-at-size spot check; TPU timing comes from the roofline.
 

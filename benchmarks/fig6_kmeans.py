@@ -15,6 +15,7 @@ from typing import Dict, List
 
 import jax
 
+from repro import compat
 from repro.analytics import kmeans as km
 from repro.analytics.engine import AnalyticsEngine
 from repro.core.pilot_data import PilotDataRegistry
@@ -24,7 +25,7 @@ SCALE = 16  # divide paper scenario sizes by this on the CPU container
 
 def run(scale: int = SCALE, use_kernel: bool = False) -> List[Dict]:
     rows = []
-    mesh = jax.make_mesh((len(jax.devices()), 1), ("data", "model"))
+    mesh = compat.make_mesh((len(jax.devices()), 1), ("data", "model"))
     for scen, (n_pts, n_clu) in km.PAPER_SCENARIOS.items():
         n = max(256, n_pts // scale)
         k = max(4, n_clu // scale)

@@ -28,6 +28,8 @@ def main() -> None:
                              "autotune", "roofline", "chaos"])
     args = ap.parse_args()
 
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (bench_autotune, bench_chaos, bench_dispatch,
                             bench_elastic, bench_fairshare, bench_kernels,
                             bench_session_placement,

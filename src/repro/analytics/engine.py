@@ -24,13 +24,14 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
+from repro.compat import require_auto_axes, shard_map
 from repro.core.dataplane import DataPlane, Link
 
 
 class AnalyticsEngine:
     def __init__(self, mesh: Mesh, data: Optional[DataPlane] = None,
                  axis: str = "data"):
+        require_auto_axes(mesh, "AnalyticsEngine")
         self.mesh = mesh
         self.axis = axis
         self.data = data or DataPlane()
@@ -95,12 +96,14 @@ class AnalyticsEngine:
         return self.data.reshard_to(name, want, link=Link.ICI,
                                     reason="ensure-local")
 
-    def global_reshard(self, name: str, spool_dir: str = "/tmp") -> jax.Array:
+    def global_reshard(self, name: str,
+                       spool_dir: Optional[str] = None) -> jax.Array:
         """Global-FS path (Lustre analogue): per the paper, hybrid stages
         "involve persisting files and re-reading them" — the dataset is
         written out through the 'parallel filesystem' and re-read before
         re-blocking, vs the data-local path that computes on resident
-        shards. Moved bytes recorded both ways."""
+        shards. Moved bytes recorded both ways.  The spool file goes to
+        ``spool_dir``, else the temp directory (``TMPDIR``)."""
         import os
         import tempfile
 

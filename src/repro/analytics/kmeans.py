@@ -39,7 +39,10 @@ def assign_partials(points: jax.Array, centroids: jax.Array, *,
         assign, mind = ref.assign(points, centroids)
     k = centroids.shape[0]
     onehot = jax.nn.one_hot(assign, k, dtype=points.dtype)        # (n, k)
-    sums = jnp.einsum("nk,nd->kd", onehot, points)
+    # f32 sums: the TPU's default matmul precision would round the points
+    # to bf16 before they move the centroids
+    sums = jnp.einsum("nk,nd->kd", onehot, points,
+                      precision=jax.lax.Precision.HIGHEST)
     counts = onehot.sum(axis=0)
     return sums, counts, jnp.sum(mind)
 

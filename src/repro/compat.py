@@ -1,73 +1,30 @@
-"""Version compatibility shims for the jax API surface we depend on.
-
-``shard_map`` moved from ``jax.experimental.shard_map`` to ``jax.shard_map``
-and renamed its replication-check flag ``check_rep`` -> ``check_vma``
-along the way; the container's pinned jax may sit on either side.
-"""
+"""Mesh and shard_map helpers with the defaults this repo relies on."""
 from __future__ import annotations
 
 import jax
 
 
-def set_mesh(mesh):
-    """Context manager installing `mesh` as the ambient mesh.
-
-    ``jax.set_mesh`` on new jax; ``jax.sharding.use_mesh`` or the Mesh's
-    own context manager on older releases.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return mesh  # Mesh is itself a context manager on old jax
-
-
 def make_mesh(axis_shapes, axis_names, *, explicit: bool = False, **kw):
-    """jax.make_mesh; the ``axis_types`` kwarg only exists on new jax
-    (old jax meshes are always Auto, which is what we want anyway)."""
-    try:
-        types = (jax.sharding.AxisType.Explicit if explicit
-                 else jax.sharding.AxisType.Auto,) * len(axis_names)
-        return jax.make_mesh(axis_shapes, axis_names,
-                             axis_types=types, **kw)
-    except (AttributeError, TypeError):
-        return jax.make_mesh(axis_shapes, axis_names, **kw)
+    """jax.make_mesh with Auto axes unless ``explicit``: jax.make_mesh
+    defaults to Explicit axes, under which the gathers and sharding
+    constraints of the models and the analytics engine do not trace."""
+    types = (jax.sharding.AxisType.Explicit if explicit
+             else jax.sharding.AxisType.Auto,) * len(axis_names)
+    return jax.make_mesh(axis_shapes, axis_names, axis_types=types, **kw)
 
 
-_barrier_impl = None
-
-
-def optimization_barrier(x):
-    """optimization_barrier, differentiable on every jax we support.
-
-    Old jax ships the primitive without a differentiation rule; there we
-    barrier the forward value and pass cotangents through unchanged
-    (the barrier is a scheduling hint, not a semantic op).  Resolved
-    lazily on first call: probing differentiability runs a real jax
-    computation, and importing this module must never initialize the
-    backend (the dry-run sets XLA_FLAGS before first device use).
-    """
-    global _barrier_impl
-    if _barrier_impl is None:
-        try:
-            jax.grad(lambda v: jax.lax.optimization_barrier(v))(1.0)
-            _barrier_impl = jax.lax.optimization_barrier
-        except Exception:
-            @jax.custom_vjp
-            def barrier(v):
-                return jax.lax.optimization_barrier(v)
-
-            barrier.defvjp(lambda v: (barrier(v), None),
-                           lambda _, ct: (ct,))
-            _barrier_impl = barrier
-    return _barrier_impl(x)
+def require_auto_axes(mesh, who: str) -> None:
+    """Refuse a mesh with Explicit axes (see :func:`make_mesh`)."""
+    explicit = [n for n, t in zip(mesh.axis_names, mesh.axis_types)
+                if t == jax.sharding.AxisType.Explicit]
+    if explicit:
+        raise ValueError(
+            f"{who} needs a mesh with Auto axes; axes {explicit} are "
+            "Explicit. Build it with repro.compat.make_mesh (or "
+            "jax.make_mesh(..., axis_types=(AxisType.Auto,) * n)).")
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """jax.shard_map across jax versions (new-style kwargs)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
+    """jax.shard_map with the replication check off by default."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

@@ -26,6 +26,8 @@ import numpy as np
 import jax
 from jax.sharding import Mesh
 
+from repro.roofline.terms import chip_spec
+
 from .agent import Agent
 from .control_plane import ControlPlane
 from .dataplane import DataPlane
@@ -52,12 +54,13 @@ class PilotDescription:
     app_master_overhead_s: float = 0.0
     n_spawners: Optional[int] = None  # executor threads (None: auto-size)
     enable_speculation: bool = True
-    # advertised per-chip speeds (defaults: TPU v5e, roofline.terms.HW).
-    # The Session placer turns a stage's StageCost into a roofline
-    # est_runtime on THIS pilot from these two numbers — heterogeneous
-    # pilots (HPC vs analytics partitions) advertise different ones.
-    peak_flops_per_chip: float = 197e12   # FLOP/s
-    hbm_bw_per_chip: float = 819e9        # B/s
+    # advertised per-chip speeds (None: the peaks of the granted chips,
+    # roofline.terms.chip_spec).  The Session placer turns a stage's
+    # StageCost into a roofline est_runtime on THIS pilot from these two
+    # numbers — heterogeneous pilots (HPC vs analytics partitions)
+    # advertise different ones.
+    peak_flops_per_chip: Optional[float] = None   # FLOP/s
+    hbm_bw_per_chip: Optional[float] = None       # B/s
     scheduler_policy: Any = "fifo"    # 'fifo' | 'capacity' | 'drf' | instance
     queues: Optional[Sequence] = None  # QueueConfigs for the tenant queues
     # tiered staging pipeline (paper: data-staging to/from HDFS around
@@ -86,6 +89,14 @@ class Pilot:
         self.state = PilotState.PENDING
         self.timings["t_pending"] = time.monotonic()
         self.devices = self.rm.grant(self.desc.n_chips, self.uid)
+        if self.devices and None in (self.desc.peak_flops_per_chip,
+                                     self.desc.hbm_bw_per_chip):
+            hw = chip_spec(self.devices[0])
+            self.desc = dataclasses.replace(
+                self.desc,
+                peak_flops_per_chip=(self.desc.peak_flops_per_chip
+                                     or hw.peak_flops),
+                hbm_bw_per_chip=self.desc.hbm_bw_per_chip or hw.hbm_bw)
         self.agent = Agent(self, reuse_app_master=self.desc.reuse_app_master,
                            app_master_overhead_s=self.desc.app_master_overhead_s,
                            n_spawners=self.desc.n_spawners,

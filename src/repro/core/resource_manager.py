@@ -19,13 +19,18 @@ from typing import Any, Dict, List, Optional, Sequence
 
 import jax
 
-HBM_BYTES_PER_CHIP = 16 * 1024**3  # TPU v5e
+from repro.roofline.terms import V5E, chip_spec
 
 
 class ResourceManager:
     def __init__(self, devices: Optional[Sequence] = None,
-                 hbm_per_chip: int = HBM_BYTES_PER_CHIP):
+                 hbm_per_chip: Optional[int] = None):
+        """``hbm_per_chip`` None: the HBM of the pooled chips
+        (:func:`repro.roofline.terms.chip_spec`)."""
         self._devices = list(devices if devices is not None else jax.devices())
+        if hbm_per_chip is None:
+            hw = chip_spec(self._devices[0]) if self._devices else V5E
+            hbm_per_chip = int(hw.hbm_bytes)
         self._leased: Dict[int, str] = {}  # device index -> pilot id
         self._failed: set[int] = set()
         self._lock = threading.Lock()

@@ -2,10 +2,27 @@
 
 Each kernel ships as a triple:
   <name>/<name>.py — pl.pallas_call + BlockSpec VMEM tiling (TPU target)
-  <name>/ops.py    — jit'd public wrapper (padding, interpret fallback)
+  <name>/ops.py    — jit'd public wrapper (padding, block choice)
   <name>/ref.py    — pure-jnp oracle used by the allclose sweeps
 
-On this CPU container kernels are validated with interpret=True; on TPU
-set ``repro.kernels.INTERPRET = False`` (ops modules read it per call).
+Kernels run compiled on the accelerator and under the Pallas interpreter
+only where the call is lowered for the CPU (:func:`pallas_on_platform`).
 """
-INTERPRET = True  # CPU container: execute kernel bodies via the interpreter
+from __future__ import annotations
+
+import functools
+from typing import Callable
+
+import jax
+
+
+def pallas_on_platform(kernel: Callable, *args, **kwargs):
+    """Call ``kernel(*args, interpret=..., **kwargs)`` with interpret mode
+    chosen by the platform the enclosing computation is lowered for: the
+    interpreter on the CPU, the compiled kernel everywhere else.  Only the
+    branch of that platform is lowered."""
+    def branch(interpret: bool):
+        return functools.partial(kernel, interpret=interpret, **kwargs)
+
+    return jax.lax.platform_dependent(*args, cpu=branch(True),
+                                      default=branch(False))

@@ -11,8 +11,9 @@ wrappers consult the registry by default — :func:`lookup` is a dict
 probe, no timing — and fall back to the legacy constants on a miss.
 
 Registry location: ``REPRO_AUTOTUNE_REGISTRY`` env var, else
-``~/.cache/repro/autotune.json``.  A corrupt registry file degrades to
-an empty one (defaults win) instead of crashing the caller.
+``.cache/autotune.json`` inside the checkout (``repro.launch.cache``).
+A corrupt registry file degrades to an empty one (defaults win) instead
+of crashing the caller.
 
 CLI (HPC-Wales-style automated environment tuning):
 
@@ -36,11 +37,13 @@ KERNELS = ("flash_attention", "kmeans", "mamba_scan")
 DEFAULTS: Dict[str, Dict[str, int]] = {
     "flash_attention": {"bq": 256, "bk": 256},
     "kmeans": {"bn": 1024, "bk": 512},
-    "mamba_scan": {"bdi": 512, "bs": 16},
+    # bdi=512 at st=16 needs 16 MiB of VMEM for the a/b blocks alone once
+    # st is padded to 128 lanes and double-buffered: v5e refuses it
+    "mamba_scan": {"bdi": 256, "bs": 16},
 }
 
-# ~16 MiB VMEM per TPU core; keep headroom for the compiler's own
-# double-buffering of revisited blocks
+# ~16 MiB scoped VMEM per TPU core; keep headroom for the compiler's own
+# scratch and the kernel body's temporaries
 VMEM_BUDGET_BYTES = 12 * 2 ** 20
 
 _BLOCKS = (64, 128, 256, 512, 1024, 2048)       # candidate tile edges
@@ -73,10 +76,8 @@ def shape_bucket(kernel: str, shape: Dict[str, int]) -> str:
 
 # --------------------------------------------------------------- registry
 def _default_path() -> str:
-    return os.environ.get(
-        "REPRO_AUTOTUNE_REGISTRY",
-        os.path.join(os.path.expanduser("~"), ".cache", "repro",
-                     "autotune.json"))
+    from repro.launch.cache import autotune_registry_path
+    return autotune_registry_path()
 
 
 class Registry:
@@ -150,15 +151,13 @@ def default_registry(reload: bool = False) -> Registry:
 
 
 def backend_tag() -> str:
-    """Registry backend axis: the jax platform, suffixed when kernels
-    run under the Pallas interpreter (interpret timings must never be
-    mistaken for compiled-TPU timings)."""
+    """Registry backend axis: the jax platform, suffixed where kernels
+    run under the Pallas interpreter — on the CPU, the same rule as
+    :func:`repro.kernels.pallas_on_platform` (interpret timings must
+    never be mistaken for compiled-TPU timings)."""
     import jax
-    import repro.kernels as K
     tag = jax.default_backend()
-    if K.INTERPRET:
-        tag += "+interpret"
-    return tag
+    return tag + "+interpret" if tag == "cpu" else tag
 
 
 def lookup(kernel: str, shape: Dict[str, int],
@@ -178,6 +177,12 @@ def lookup(kernel: str, shape: Dict[str, int],
 # ------------------------------------------------------------- candidates
 def _f32(nelem: float) -> float:
     return 4.0 * nelem
+
+
+def _tile_f32(rows: int, cols: int) -> float:
+    """VMEM bytes of an f32 (rows, cols) tile as the TPU lays it out: the
+    minor dim padded to 128 lanes, the second-minor to 8 sublanes."""
+    return _f32(-(-rows // 8) * 8 * (-(-cols // 128) * 128))
 
 
 def candidates_flash(S_q: int, S_k: int, hd: int,
@@ -228,17 +233,20 @@ def candidates_mamba(S: int, di: int, st: int,
                      ) -> List[Dict[str, int]]:
     """(bdi, bs) grid: bdi snapped to d_inner divisors, bs to sequence
     divisors (the unrolled time loop caps bs — past ~128 the kernel
-    body explodes)."""
+    body explodes).  The VMEM estimate counts what the compiler
+    allocates: ``st`` padded to 128 lanes, and two buffers for every
+    pipelined block (inputs and outputs); only the h scratch is single."""
     out, seen = [], set()
     for bdi_w in _BLOCKS:
         for bs_w in _SMALL_BLOCKS:
             bdi = snap_block(di, bdi_w)
             bs = snap_block(S, bs_w)
-            vmem = (2 * _f32(bs * bdi * st)   # a, b blocks
-                    + _f32(bs * st)           # C block
-                    + _f32(bdi * st)          # h0 block
-                    + _f32(bs * bdi)          # y block
-                    + 2 * _f32(bdi * st))     # h_out block + h scratch
+            vmem = (2 * (2 * bs * _tile_f32(bdi, st)   # a, b blocks
+                         + _tile_f32(bs, st)           # C block
+                         + _tile_f32(bdi, st)          # h0 block
+                         + _tile_f32(bs, bdi)          # y block
+                         + _tile_f32(bdi, st))         # h_out block
+                    + _tile_f32(bdi, st))              # h scratch
             if vmem > budget or (bdi, bs) in seen:
                 continue
             seen.add((bdi, bs))
@@ -385,9 +393,7 @@ def autotune(kernel: str, shape: Optional[Dict[str, int]] = None, *,
 
 # -------------------------------------------------------------------- CLI
 def main(argv: Optional[Sequence[str]] = None) -> None:
-    from repro.launch import platform as _platform
-    _platform.configure()                   # XLA flags before backend init
-    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap =argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("kernel", choices=list(KERNELS) + ["all"],
                     help="kernel family to tune (or 'all')")
     ap.add_argument("--shapes", default=None, metavar="JSON",
@@ -396,7 +402,7 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
     ap.add_argument("--reps", type=int, default=3)
     ap.add_argument("--registry", default=None,
                     help="registry path (default: REPRO_AUTOTUNE_REGISTRY "
-                         "or ~/.cache/repro/autotune.json)")
+                         "or .cache/autotune.json in the checkout)")
     ap.add_argument("--force", action="store_true",
                     help="re-time even on a registry hit")
     args = ap.parse_args(argv)

@@ -82,7 +82,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *,
 def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                            causal: bool = True, window: int = 0,
                            bq: int = 512, bk: int = 512,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool) -> jax.Array:
     """q,k,v: (BH, S, hd) flattened batch*heads -> (BH, S, hd)."""
     BH, S_q, hd = q.shape
     S_k = k.shape[1]

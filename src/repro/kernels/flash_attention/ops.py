@@ -7,8 +7,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-import repro.kernels as K
-from repro.kernels import autotune
+from repro.kernels import autotune, pallas_on_platform
 from . import flash_attention as kernel
 
 
@@ -19,9 +18,9 @@ def _attention(q, k, v, causal: bool, window: int, bq: int, bk: int):
     def flat(x):
         return x.swapaxes(1, 2).reshape(B * H, x.shape[1], hd)
 
-    out = kernel.flash_attention_pallas(
-        flat(q), flat(k), flat(v), causal=causal, window=window,
-        bq=bq, bk=bk, interpret=K.INTERPRET)
+    out = pallas_on_platform(
+        kernel.flash_attention_pallas, flat(q), flat(k), flat(v),
+        causal=causal, window=window, bq=bq, bk=bk)
     return out.reshape(B, H, S_q, hd).swapaxes(1, 2)
 
 

@@ -33,7 +33,8 @@ def _kernel(p_ref, c_ref, idx_ref, min_ref, *, bk: int):
     p = p_ref[...].astype(jnp.float32)                 # (bn, d)
     c = c_ref[...].astype(jnp.float32)                 # (bk, d)
     # ||p - c||^2 = ||p||^2 - 2 p.c + ||c||^2 ; ||p||^2 constant per row
-    scores = -2.0 * jnp.dot(p, c.T, preferred_element_type=jnp.float32)
+    scores = -2.0 * jnp.dot(p, c.T, preferred_element_type=jnp.float32,
+                            precision=jax.lax.Precision.HIGHEST)
     scores = scores + jnp.sum(c * c, axis=1)[None, :]  # (bn, bk)
     local_min = jnp.min(scores, axis=1)
     local_arg = jnp.argmin(scores, axis=1).astype(jnp.int32) + j * bk
@@ -45,7 +46,7 @@ def _kernel(p_ref, c_ref, idx_ref, min_ref, *, bk: int):
 
 
 def assign_pallas(points: jax.Array, centroids: jax.Array, *,
-                  bn: int = 1024, bk: int = 512, interpret: bool = True):
+                  bn: int = 1024, bk: int = 512, interpret: bool):
     """points (n,d) f32, centroids (k,d) f32 -> (idx (n,) i32, partial min).
 
     Returned min excludes the ||p||^2 term (constant per point) — ops.py

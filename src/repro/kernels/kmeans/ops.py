@@ -7,8 +7,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-import repro.kernels as K
-from repro.kernels import autotune
+from repro.kernels import autotune, pallas_on_platform
 from . import kmeans as kernel
 
 _PAD_VALUE = 1e8  # padded centroids land far away from every point
@@ -26,8 +25,8 @@ def _assign(points, centroids, bn: int, bk: int):
     p = jnp.pad(points.astype(jnp.float32), ((0, np_ - n), (0, 0)))
     c = jnp.pad(centroids.astype(jnp.float32), ((0, kp - k), (0, 0)),
                 constant_values=_PAD_VALUE)
-    idx, partial_min = kernel.assign_pallas(p, c, bn=bn, bk=bk,
-                                            interpret=K.INTERPRET)
+    idx, partial_min = pallas_on_platform(kernel.assign_pallas, p, c,
+                                          bn=bn, bk=bk)
     mind = partial_min + jnp.sum(points.astype(jnp.float32) ** 2, axis=1) \
         if np_ == n else (partial_min[:n]
                           + jnp.sum(points.astype(jnp.float32) ** 2, axis=1))

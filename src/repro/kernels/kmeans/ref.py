@@ -14,7 +14,7 @@ def assign(points: jax.Array, centroids: jax.Array
     p = points.astype(jnp.float32)
     c = centroids.astype(jnp.float32)
     d2 = (jnp.sum(p * p, axis=1, keepdims=True)
-          - 2.0 * p @ c.T
+          - 2.0 * jnp.dot(p, c.T, precision=jax.lax.Precision.HIGHEST)
           + jnp.sum(c * c, axis=1)[None, :])          # (n, k)
     idx = jnp.argmin(d2, axis=1).astype(jnp.int32)
     return idx, jnp.take_along_axis(d2, idx[:, None], axis=1)[:, 0]

@@ -49,8 +49,8 @@ def _kernel(a_ref, b_ref, c_ref, h0_ref, y_ref, hout_ref, h_s, *,
 
 
 def mamba_scan_pallas(a: jax.Array, b: jax.Array, C: jax.Array,
-                      h0: jax.Array, *, bdi: int = 512, bs: int = 16,
-                      interpret: bool = True):
+                      h0: jax.Array, *, bdi: int = 256, bs: int = 16,
+                      interpret: bool):
     """a,b: (B,S,di,st); C: (B,S,st); h0: (B,di,st) -> (y (B,S,di), h_last)."""
     B, S, di, st = a.shape
     assert S % bs == 0 and di % bdi == 0, (S, di, bs, bdi)

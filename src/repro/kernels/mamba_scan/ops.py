@@ -7,15 +7,14 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-import repro.kernels as K
-from repro.kernels import autotune
+from repro.kernels import autotune, pallas_on_platform
 from . import mamba_scan as kernel
 
 
 @functools.partial(jax.jit, static_argnames=("bdi", "bs"))
 def _scan(a, b, C, h0, bdi: int, bs: int):
-    return kernel.mamba_scan_pallas(a, b, C, h0, bdi=bdi, bs=bs,
-                                    interpret=K.INTERPRET)
+    return pallas_on_platform(kernel.mamba_scan_pallas, a, b, C, h0,
+                              bdi=bdi, bs=bs)
 
 
 def resolve_blocks(S: int, di: int, st: int, dtype,

@@ -12,7 +12,6 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import configs
@@ -22,12 +21,12 @@ from repro.models import transformer
 from repro.models.config import SHAPES, ModelConfig, ShapeConfig, shape_applicable
 from repro.roofline import analytic
 from repro.roofline.hlo import collective_bytes_per_device
-from repro.roofline.terms import roofline_terms
+from repro.roofline.terms import V5E, roofline_terms
 from repro.serve import make_decode_step, make_prefill_step
 from repro.sharding import Plan
 from repro.train import make_train_state, make_train_step, microbatch_count
 
-HBM_PER_CHIP = 16e9  # TPU v5e
+HBM_PER_CHIP = V5E.hbm_bytes  # the dry-run models v5e pods
 
 
 def _named(mesh, tree):
@@ -238,7 +237,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     plan = Plan.for_mesh(mesh)
     t0 = time.time()
     fn, args, extra = build_cell(cfg, shape, mesh, plan, overrides)
-    with compat.set_mesh(mesh):   # set_mesh: populates the abstract mesh that
+    with jax.set_mesh(mesh):   # set_mesh: populates the abstract mesh that
         lowered = fn.lower(*args)  # the MoE EP shard_map path reads
         rec["lower_s"] = round(time.time() - t0, 2)
         t1 = time.time()

@@ -1,11 +1,19 @@
-"""Serving launcher: batched prefill+decode of a small model on a Pilot.
+"""Serving launcher: requests through the continuous-batching engine on a
+Pilot, at the smoke or the full width of an architecture.
 
-``python -m repro.launch.serve --arch llama3.2-1b --requests 8 --gen 16``
+``python -m repro.launch.serve --arch llama3.2-1b [--full-config]
+--requests 8 --prompt-len 256 --gen 16``
+
+Plain language models are served by :class:`~repro.serve.engine.
+ServeEngine` over a :class:`~repro.serve.engine.ModelBackend`
+(:func:`serve_requests`); architectures the engine does not take (vision
+frontends, encoder-decoders) by the static batch of :func:`serve_batch`.
 """
 from __future__ import annotations
 
 import argparse
 import time
+from typing import Dict, List, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -14,8 +22,32 @@ import numpy as np
 from repro import configs
 from repro.core import PilotDescription, PilotManager, ComputeUnitDescription
 from repro.data.batches import make_batch
+from repro.launch.cache import enable_compile_cache
 from repro.models import transformer
 from repro.serve import make_decode_step
+from repro.serve.engine import Request, ServeEngine
+
+
+def serve_requests(cfg, params, prompts: Sequence[np.ndarray], *, gen: int,
+                   slots: int = 4, prompt_bucket: int = 32,
+                   timeout_s: float = 900.0) -> Dict[str, object]:
+    """Serve ``prompts`` (1-D token arrays) greedily through a
+    ServeEngine with a ModelBackend; ``gen`` new tokens each.  Returns the
+    generated tokens per prompt, the decode steps and the host seconds
+    from first submit to drain (compiles included on a cold start)."""
+    longest = max(len(p) for p in prompts)
+    bucket = -(-longest // prompt_bucket) * prompt_bucket
+    engine = ServeEngine(cfg, params, slots=slots, max_seq=bucket + gen + 1,
+                         prompt_bucket=prompt_bucket)
+    reqs: List[Request] = [
+        Request(uid=i, tokens=np.asarray(p, np.int32), max_new=gen)
+        for i, p in enumerate(prompts)]
+    t0 = time.monotonic()
+    for r in reqs:
+        engine.submit(r)
+    steps = engine.run_until_drained(timeout_s)
+    return {"outputs": [r.output for r in reqs], "steps": steps,
+            "wall_s": time.monotonic() - t0}
 
 
 def serve_batch(cfg, *, n_requests: int, prompt_len: int, gen: int,
@@ -57,23 +89,40 @@ def serve_batch(cfg, *, n_requests: int, prompt_len: int, gen: int,
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b", choices=configs.names())
+    ap.add_argument("--full-config", action="store_true",
+                    help="serve the full architecture config (default: "
+                         "its reduced smoke config)")
     ap.add_argument("--requests", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=16)
     args = ap.parse_args()
 
-    cfg = configs.get_smoke(args.arch)
+    enable_compile_cache()
+    cfg = configs.get(args.arch) if args.full_config else configs.get_smoke(args.arch)
     pm = PilotManager()
     pilot = pm.submit(PilotDescription(n_chips=1, name="serve"))
+
+    def job(mesh=None):
+        if cfg.frontend != "none" or cfg.is_encoder_decoder:
+            return serve_batch(cfg, n_requests=args.requests,
+                               prompt_len=args.prompt_len, gen=args.gen)
+        params = transformer.init_params(cfg, jax.random.key(0))
+        rng = np.random.default_rng(0)
+        prompts = [rng.integers(0, cfg.vocab_size, args.prompt_len)
+                   for _ in range(args.requests)]
+        return serve_requests(cfg, params, prompts, gen=args.gen)
+
     cu = pilot.submit(ComputeUnitDescription(
-        fn=lambda mesh=None: serve_batch(cfg, n_requests=args.requests,
-                                         prompt_len=args.prompt_len,
-                                         gen=args.gen),
-        n_chips=1, gang=True, tag="serve"))
-    res = cu.wait(600)
-    print(f"prefill {res['prefill_s']*1e3:.0f} ms, "
-          f"decode {res['decode_s']*1e3:.0f} ms, "
-          f"{res['tok_per_s']:.1f} tok/s, tokens shape {res['tokens'].shape}")
+        fn=job, n_chips=1, gang=True, tag="serve"))
+    res = cu.wait(3600)
+    if "outputs" in res:
+        print(f"{len(res['outputs'])} requests x {args.gen} tokens in "
+              f"{res['steps']} decode steps, {res['wall_s']:.2f} s host time "
+              f"(compiles included)")
+    else:
+        print(f"prefill {res['prefill_s']*1e3:.0f} ms, "
+              f"decode {res['decode_s']*1e3:.0f} ms, "
+              f"{res['tok_per_s']:.1f} tok/s, tokens shape {res['tokens'].shape}")
     pm.shutdown()
 
 

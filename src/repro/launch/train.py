@@ -1,8 +1,7 @@
 """Training launcher: ``python -m repro.launch.train --arch <id> ...``
 
-Runs the reduced (smoke) config of the selected architecture by default
-— the full configs are dry-run-only on this CPU container. The training
-job executes as a gang-scheduled Compute-Unit on a Pilot (Mode-I-ready:
+Runs the reduced (smoke) config of the selected architecture by default,
+the full one with ``--full-config``. The training job executes as a gang-scheduled Compute-Unit on a Pilot (Mode-I-ready:
 spawn an analytics cluster next to it; see examples/hybrid_pipeline.py).
 """
 from __future__ import annotations
@@ -13,6 +12,7 @@ import jax
 
 from repro import configs
 from repro.core import PilotDescription, PilotManager, ComputeUnitDescription
+from repro.launch.cache import enable_compile_cache
 from repro.optim import adamw
 from repro.train.trainer import Trainer
 
@@ -21,7 +21,8 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b", choices=configs.names())
     ap.add_argument("--full-config", action="store_true",
-                    help="use the full architecture config (TPU pods only)")
+                    help="train the full architecture config (default: "
+                         "its reduced smoke config)")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
@@ -33,6 +34,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=50)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = configs.get(args.arch) if args.full_config else configs.get_smoke(args.arch)
     pm = PilotManager()
     pilot = pm.submit(PilotDescription(n_chips=args.n_chips, tp=args.tp,

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 
 from jax.ad_checkpoint import checkpoint_name
 
-from repro import compat
 from repro.models.config import ModelConfig
 from repro.models.layers import attention as attn_lib
 from repro.models.layers import common, mamba as mamba_lib, moe as moe_lib
@@ -271,7 +270,7 @@ def _run_segments(cfg, segs, seg_params, x, positions, enc_out=None, *,
             # barrier: stops XLA from hoisting a convert of the *stacked*
             # saved-residual buffer out of the backward loop (which would
             # materialize a whole-model f32 activation copy)
-            carry = compat.optimization_barrier(carry)
+            carry = jax.lax.optimization_barrier(carry)
             carry = _grad_dtype_guard(carry)
             y, cache, aux = block_forward(cfg, seg, lp, carry, positions,
                                           enc_out, moe_groups, moe_ep_axis,

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from repro.models import transformer
 from repro.models.config import ModelConfig
 from repro.optim import adamw, schedule
+from repro.roofline.terms import V5E
 
 TrainState = Dict[str, Any]
 
@@ -29,7 +30,7 @@ def make_train_state(cfg: ModelConfig, params: Any,
 
 
 def microbatch_count(cfg: ModelConfig, global_batch: int, seq: int,
-                     n_devices: int, hbm_bytes: float = 16e9) -> int:
+                     n_devices: int, hbm_bytes: float = V5E.hbm_bytes) -> int:
     """Pick a grad-accumulation factor so stored activations fit HBM.
 
     Per-layer remat stores one (mb, S, D) residual per layer; target that

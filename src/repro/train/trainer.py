@@ -37,6 +37,7 @@ class Trainer:
                  n_microbatches: int = 1, ckpt_dir: Optional[str] = None,
                  ckpt_every: int = 50, seed: int = 0,
                  warmup_steps: int = 10, total_steps: int = 1000):
+        compat.require_auto_axes(mesh, "Trainer")
         self.cfg = cfg
         self.mesh = mesh
         self.plan = Plan.for_mesh(mesh)
@@ -70,7 +71,7 @@ class Trainer:
 
     # -------------------------------------------------------------- state
     def init_state(self) -> None:
-        with compat.set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             init = jax.jit(
                 lambda k: make_train_state(
                     self.cfg, transformer.init_params(self.cfg, k)),
@@ -99,7 +100,7 @@ class Trainer:
                  else int(jax.device_get(self.state["step"])))
         self.pipeline.start(from_step=step0)
         try:
-            with compat.set_mesh(self.mesh):
+            with jax.set_mesh(self.mesh):
                 for i, batch in zip(range(step0, n_steps), self.pipeline):
                     if inject_failure_at is not None and i == inject_failure_at:
                         raise RuntimeError("injected node failure")

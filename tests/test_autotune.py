@@ -125,6 +125,15 @@ def test_candidates_respect_vmem_budget():
     assert small and all(c["bq"] <= 128 for c in small)
 
 
+def test_mamba_vmem_filter_refuses_what_v5e_refuses():
+    """At falcon-mamba widths (di=8192, st=16) the v5e compiler runs out of
+    VMEM at bdi=512, bs=16: st pads to 128 lanes and every block is
+    double-buffered.  The filter must refuse it and keep the default."""
+    cands = at.candidates_mamba(2048, 8192, 16)
+    assert {"bdi": 512, "bs": 16} not in cands
+    assert at.DEFAULTS["mamba_scan"] in cands
+
+
 def test_candidates_snap_to_shape_divisors():
     for c in at.candidates_flash(384, 384, 64):
         assert 384 % c["bq"] == 0 and 384 % c["bk"] == 0
@@ -160,7 +169,7 @@ def test_ops_wrappers_default_without_registry(tmp_path, monkeypatch):
     assert fa.resolve_blocks(1024, 1024, 64, jnp.float32, None, None) == \
         (256, 256)                            # legacy constants survive
     assert ms.resolve_blocks(256, 512, 16, jnp.float32, None, None) == \
-        (512, 16)
+        (256, 16)                             # shipped default (v5e-legal)
     at.default_registry(reload=True)
 
 

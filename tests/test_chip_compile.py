@@ -1,0 +1,105 @@
+"""Compile every Pallas kernel for a described TPU v5e chip, at the widths
+the main path runs them, with the blocks ``resolve_blocks`` picks.
+
+Nothing runs: the TPU compiler lowers each kernel for a chip that is
+described, not attached, and refuses what the chip would refuse (VMEM
+overflow, tile misalignment).  The topology is described inside a
+module-scoped fixture only: the TPU library may be loaded by one process
+at a time, and every pytest worker imports this file.
+"""
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import autotune
+from repro.kernels.flash_attention import flash_attention as fa_kernel
+from repro.kernels.flash_attention import ops as fa_ops
+from repro.kernels.kmeans import kmeans as km_kernel
+from repro.kernels.kmeans import ops as km_ops
+from repro.kernels.mamba_scan import mamba_scan as ms_kernel
+from repro.kernels.mamba_scan import ops as ms_ops
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # entries written for a described chip cannot be read back without one
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+@pytest.fixture(autouse=True)
+def shipped_blocks(monkeypatch, tmp_path):
+    """Resolve blocks from the shipped DEFAULTS, never a local registry."""
+    monkeypatch.setenv("REPRO_AUTOTUNE_REGISTRY", str(tmp_path / "none.json"))
+    autotune.default_registry(reload=True)
+    yield
+    autotune.default_registry(reload=True)
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *specs):
+    compiled = jax.jit(fn).lower(*specs).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+@pytest.mark.parametrize("n,k", [(1_000_000, 50), (1_000_000, 5_000)])
+def test_kmeans_assign_compiles(one_chip, n, k):
+    """The paper's 1M x 3 scenario, with 50 and 5,000 centroids."""
+    d = 3
+    bn, bk = km_ops.resolve_blocks(n, k, d, jnp.float32, None, None)
+    n_pad, k_pad = -(-n // bn) * bn, -(-k // bk) * bk
+    fn = functools.partial(km_kernel.assign_pallas, bn=bn, bk=bk,
+                           interpret=False)
+    _compile(fn, _spec((n_pad, d), jnp.float32, one_chip),
+             _spec((k_pad, d), jnp.float32, one_chip))
+
+
+@pytest.mark.parametrize("hd", [64, 128])
+def test_flash_attention_compiles(one_chip, hd):
+    """llama3.2-1b widths (hd=64, 32 heads, S=2048) in bf16."""
+    BH, S = 32, 2048
+    bq, bk = fa_ops.resolve_blocks(S, S, hd, jnp.bfloat16, None, None)
+    fn = functools.partial(fa_kernel.flash_attention_pallas, bq=bq, bk=bk,
+                           interpret=False)
+    spec = _spec((BH, S, hd), jnp.bfloat16, one_chip)
+    _compile(fn, spec, spec, spec)
+
+
+def test_mamba_scan_compiles(one_chip):
+    """falcon-mamba widths: d_inner 8192, state 16."""
+    B, S, di, st = 1, 256, 8192, 16
+    bdi, bs = ms_ops.resolve_blocks(S, di, st, jnp.float32, None, None)
+    fn = functools.partial(ms_kernel.mamba_scan_pallas, bdi=bdi, bs=bs,
+                           interpret=False)
+    _compile(fn, _spec((B, S, di, st), jnp.float32, one_chip),
+             _spec((B, S, di, st), jnp.float32, one_chip),
+             _spec((B, S, st), jnp.float32, one_chip),
+             _spec((B, di, st), jnp.float32, one_chip))
+
+
+def test_kmeans_ops_wrapper_picks_compiled_kernel(one_chip):
+    """The public wrapper, lowered for the chip, takes the compiled branch
+    (interpret mode is chosen per lowering platform, not by a global)."""
+    _compile(lambda p, c: km_ops.assign(p, c),
+             _spec((10_240, 3), jnp.float32, one_chip),
+             _spec((5_120, 3), jnp.float32, one_chip))

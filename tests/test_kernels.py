@@ -1,4 +1,5 @@
-"""Per-kernel shape/dtype sweeps: pallas (interpret=True) vs pure-jnp oracle."""
+"""Per-kernel shape/dtype sweeps: pallas (interpreted on the CPU) vs
+pure-jnp oracle."""
 import jax
 import jax.numpy as jnp
 import numpy as np

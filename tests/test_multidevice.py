@@ -94,8 +94,7 @@ def test_pilot_gang_mesh_multidevice():
 
     def hpc(mesh=None):
         assert mesh.size == 4, mesh
-        from repro import compat
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             x = jax.device_put(jnp.arange(16.0).reshape(8, 2),
                                NamedSharding(mesh, P("data", "model")))
             return float(jax.jit(lambda v: (v * v).sum())(x))

@@ -159,6 +159,12 @@ def test_calibration_opt_in():
 
 
 def test_pilot_description_advertises_roofline_defaults():
-    d = PilotDescription(n_chips=1, name="p")
-    assert d.peak_flops_per_chip == pytest.approx(197e12)   # TPU v5e
-    assert d.hbm_bw_per_chip == pytest.approx(819e9)
+    """Unset peaks come from the granted chips' device table entry; the
+    CPU backend models a TPU v5e."""
+    s = Session(ResourceManager(devices=jax.devices()))
+    try:
+        d = s.add_pilot(PilotDescription(n_chips=1, name="p")).desc
+        assert d.peak_flops_per_chip == pytest.approx(197e12)   # TPU v5e
+        assert d.hbm_bw_per_chip == pytest.approx(819e9)
+    finally:
+        s.shutdown()

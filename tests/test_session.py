@@ -7,6 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import compat
 from repro.core import (ComputeUnitDescription, PilotDescription,
                         ResourceManager, Session, TransferCostModel,
                         analytics_stage, hpc_stage)
@@ -115,7 +116,7 @@ def test_global_reshard_routes_through_ledger():
     """The GFS spool path (Lustre analogue) accounts both the persist
     and the re-read through record_moved — no private counter pokes."""
     from repro.analytics.engine import AnalyticsEngine
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = compat.make_mesh((1, 1), ("data", "model"))
     eng = AnalyticsEngine(mesh, DataPlane())
     eng.put("d", np.ones((32, 4), np.float32))
     nbytes = eng.get("d").nbytes

@@ -9,7 +9,7 @@ import pytest
 pytest.importorskip("hypothesis")  # suite degrades to skips without it
 from hypothesis import given, settings, strategies as st
 
-from repro import configs
+from repro import compat, configs
 from repro.analytics import kmeans as km
 from repro.analytics.engine import AnalyticsEngine
 from repro.checkpoint import CheckpointManager
@@ -47,7 +47,7 @@ def test_checkpoint_retention_and_latest(tmp_path):
 def test_checkpoint_restore_resharded(tmp_path):
     """Restore onto a different sharding (elastic resize path)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = compat.make_mesh((1,), ("data",))
     cm = CheckpointManager(str(tmp_path), async_save=False)
     state = {"w": jnp.arange(16, dtype=jnp.float32).reshape(4, 4)}
     cm.save(state, 1)
@@ -112,7 +112,7 @@ def test_compressed_psum_matches_fp32():
     """int8 shared-scale psum over a mesh axis ~= exact psum."""
     from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
-    mesh = jax.make_mesh((1,), ("pod",))
+    mesh = compat.make_mesh((1,), ("pod",))
     x = jnp.asarray(np.random.default_rng(1).normal(size=(8, 16)).astype(np.float32))
     res = jnp.zeros_like(x)
 
@@ -162,7 +162,7 @@ def test_adamw_scanned_update_matches_elementwise():
 
 # ---------------------------------------------------------------- analytics
 def test_map_reduce_matches_numpy():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = compat.make_mesh((1, 1), ("data", "model"))
     eng = AnalyticsEngine(mesh, PilotDataRegistry())
     x = np.random.default_rng(0).normal(size=(64, 3)).astype(np.float32)
     eng.put("x", x)
@@ -172,7 +172,7 @@ def test_map_reduce_matches_numpy():
 
 def test_kmeans_local_equals_global_path():
     """Identical math on both data paths; only movement differs."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = compat.make_mesh((1, 1), ("data", "model"))
     eng = AnalyticsEngine(mesh, PilotDataRegistry())
     pts = km.make_dataset(2048, 3, n_clusters=5, seed=1)
     eng.put("p", pts)
@@ -185,7 +185,7 @@ def test_kmeans_local_equals_global_path():
 
 
 def test_kmeans_cost_decreases_with_iters():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = compat.make_mesh((1, 1), ("data", "model"))
     eng = AnalyticsEngine(mesh, PilotDataRegistry())
     pts = km.make_dataset(4096, 3, n_clusters=6, seed=0)
     eng.put("p", pts)
@@ -195,7 +195,7 @@ def test_kmeans_cost_decreases_with_iters():
 
 
 def test_kmeans_kernel_path_matches_ref_path():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = compat.make_mesh((1, 1), ("data", "model"))
     eng = AnalyticsEngine(mesh, PilotDataRegistry())
     pts = km.make_dataset(1024, 3, n_clusters=4, seed=3)
     eng.put("p", pts)

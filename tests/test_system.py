@@ -8,7 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import configs
+from repro import compat, configs
 from repro.core import (ComputeUnitDescription, PilotDescription, PilotManager,
                         ResourceManager)
 from repro.optim import adamw
@@ -23,7 +23,7 @@ def pm():
 
 
 def _mesh1():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return compat.make_mesh((1, 1), ("data", "model"))
 
 
 def test_train_loss_decreases(tmp_path):

@@ -26,6 +26,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.compat import require_auto_axes, shard_map
 from repro.core.dataplane import DataPlane, Link
+from repro.spans import span
 
 
 class AnalyticsEngine:
@@ -43,8 +44,10 @@ class AnalyticsEngine:
 
     def put(self, name: str, array: jax.Array | np.ndarray) -> None:
         """Register a dataset, sharded block-wise over the engine's mesh."""
-        arr = jax.device_put(jnp.asarray(array), self.block_sharding())
-        self.data.put(name, arr)
+        with span("engine.put") as sp:
+            arr = jax.device_put(jnp.asarray(array), self.block_sharding())
+            self.data.put(name, arr)
+            sp.set_metadata(bytes=arr.nbytes)
 
     def get(self, name: str) -> jax.Array:
         return self.data.get(name).array
@@ -68,22 +71,23 @@ class AnalyticsEngine:
         ``cache_key`` enables executor re-use across rounds (the paper's
         container re-use: iterative algorithms pay tracing/compile once).
         """
-        x = self.ensure_local(name)
-        key = cache_key if cache_key is not None else id(map_fn)
-        fn = self._exec_cache.get(key)
-        if fn is None:
-            def shard_fn(block, *args):
-                partial = map_fn(block, *args)
-                return jax.tree.map(
-                    lambda t: jax.lax.psum(t, self.axis), partial)
+        with span("engine.map_reduce"):
+            x = self.ensure_local(name)
+            key = cache_key if cache_key is not None else id(map_fn)
+            fn = self._exec_cache.get(key)
+            if fn is None:
+                def shard_fn(block, *args):
+                    partial = map_fn(block, *args)
+                    return jax.tree.map(
+                        lambda t: jax.lax.psum(t, self.axis), partial)
 
-            extra_specs = tuple(P() for _ in extra_args)
-            fn = jax.jit(shard_map(
-                shard_fn, mesh=self.mesh,
-                in_specs=(P(self.axis),) + extra_specs,
-                out_specs=P(), check_vma=False))
-            self._exec_cache[key] = fn
-        return fn(x, *extra_args)
+                extra_specs = tuple(P() for _ in extra_args)
+                fn = jax.jit(shard_map(
+                    shard_fn, mesh=self.mesh,
+                    in_specs=(P(self.axis),) + extra_specs,
+                    out_specs=P(), check_vma=False))
+                self._exec_cache[key] = fn
+            return fn(x, *extra_args)
 
     # ----------------------------------------------------------- data paths
     def ensure_local(self, name: str) -> jax.Array:

@@ -17,6 +17,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.spans import span
+
 from .engine import AnalyticsEngine
 
 PAPER_SCENARIOS = {
@@ -57,11 +59,12 @@ def kmeans_fit(engine: AnalyticsEngine, name: str, k: int, *,
     data_path='global' — force a full redistribution first, each iteration
                          (RP / Lustre): same math, measured data movement.
     """
-    pts = engine.get(name)
-    n, d = pts.shape
-    key = jax.random.key(seed)
-    idx = jax.random.choice(key, n, (k,), replace=False)
-    centroids = pts[idx]
+    with span("kmeans.init"):
+        pts = engine.get(name)
+        n, d = pts.shape
+        key = jax.random.key(seed)
+        idx = jax.random.choice(key, n, (k,), replace=False)
+        centroids = pts[idx]
 
     cost = jnp.inf
     map_fn = functools.partial(assign_partials, use_kernel=use_kernel)
@@ -71,10 +74,13 @@ def kmeans_fit(engine: AnalyticsEngine, name: str, k: int, *,
         sums, counts, cost = engine.map_reduce(
             map_fn, name, extra_args=(centroids,),
             cache_key=("kmeans_assign", use_kernel))
-        centroids = jnp.where(counts[:, None] > 0,
-                              sums / jnp.maximum(counts[:, None], 1.0),
-                              centroids)
-    return centroids, float(cost)
+        with span("kmeans.update"):
+            centroids = jnp.where(counts[:, None] > 0,
+                                  sums / jnp.maximum(counts[:, None], 1.0),
+                                  centroids)
+    with span("kmeans.cost"):
+        cost = float(cost)
+    return centroids, cost
 
 
 def make_dataset(n: int, d: int = PAPER_DIM, *, n_clusters: int = 8,

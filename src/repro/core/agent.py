@@ -24,6 +24,8 @@ import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Dict, List, Optional, Sequence
 
+from repro.spans import span
+
 from .compute_unit import ComputeUnit, ComputeUnitDescription, CUState
 from .scheduler import YarnStyleScheduler
 
@@ -243,9 +245,12 @@ class Agent:
             # schedule_round binds and reads the binding generation in
             # ONE lock acquisition (try_schedule + per-CU binding_gen
             # used to take the lock again for every bound CU)
-            for cu, idxs, gen in self.scheduler.schedule_round():
-                cu.assigned_devices = self.scheduler.devices_of(idxs)
-                self._pool.submit(self._spawn, cu, gen)
+            with span("agent.schedule") as sp:
+                bound = self.scheduler.schedule_round()
+                for cu, idxs, gen in bound:
+                    cu.assigned_devices = self.scheduler.devices_of(idxs)
+                    self._pool.submit(self._spawn, cu, gen)
+                sp.set_metadata(bound=len(bound))
             self._check_stragglers()
             self._heartbeat()
             # event-driven wake: submits/releases/restores signal _wake
@@ -414,56 +419,80 @@ class Agent:
             self.scheduler.release(cu, gen=gen)
             self._wake.set()
             return
-        # delay budget expired with transfers still in flight: convert any
-        # unclaimed stage-in to a remote read (exactly one side wins the
-        # PENDING->REMOTE vs PENDING->IN_FLIGHT race; a transfer already
-        # claimed by a worker just finishes and the bytes stay promoted)
         prefetcher = getattr(self.pilot, "prefetcher", None)
-        if prefetcher is not None:
-            for req in cu.staging_futures:
-                prefetcher.claim_remote(req)
-        cu._set_state(CUState.RUNNING)
-        try:
-            kwargs = dict(cu.desc.kwargs)
-            if cu.desc.needs_mesh:
-                kwargs["mesh"] = self.pilot.mesh(cu.assigned_devices)
-            fn = self._launch_method(cu)
-            result = fn(*cu.desc.args, **kwargs)
-            # a speculation winner or a preemption may have resolved this
-            # CU while fn ran — never clobber the published result; a
-            # killed agent publishes nothing (its CUs were re-queued on
-            # survivors by the recovery — a late local completion must
-            # not race the clone that replaced it)
-            if self._killed or cu.done or cu.state is CUState.CANCELED:
-                return
-            cu.result = result
-            cu._set_state(CUState.DONE)
-            self._record_runtime(cu)
-            self._resolve_speculation(cu)
-            # stage-out rides the same pipeline, off the critical path:
-            # the CU is DONE before the spool to GFS even starts
-            if prefetcher is not None and cu.desc.stage_out:
-                prefetcher.request_many(
-                    cu.desc.stage_out, kind="out",
-                    priority=cu.desc.priority,
-                    reason=f"stage-out:{cu.uid}")
-        except BaseException as e:  # noqa: BLE001 — agent must survive any CU
-            if self._killed or cu.done or cu.state is CUState.CANCELED:
-                return
-            cu.error = e
-            if cu.retries < cu.desc.max_retries:
-                cu.retries += 1
-                cu._done.clear()
+        result: Any = None
+        error: Optional[BaseException] = None
+        with span("cu.spawn", cu=cu.uid, tag=cu.desc.tag):
+            # delay budget expired with transfers still in flight: convert
+            # any unclaimed stage-in to a remote read (exactly one side
+            # wins the PENDING->REMOTE vs PENDING->IN_FLIGHT race; a
+            # transfer already claimed by a worker just finishes and the
+            # bytes stay promoted)
+            if prefetcher is not None:
+                for req in cu.staging_futures:
+                    prefetcher.claim_remote(req)
+            cu._set_state(CUState.RUNNING)
+            try:
+                kwargs = dict(cu.desc.kwargs)
+                if cu.desc.needs_mesh:
+                    kwargs["mesh"] = self.pilot.mesh(cu.assigned_devices)
+                fn = self._launch_method(cu)
+            except BaseException as e:  # noqa: BLE001 — agent survives any CU
+                error = e
+        if error is None:
+            with span("cu.body", cu=cu.uid, tag=cu.desc.tag):
+                try:
+                    result = fn(*cu.desc.args, **kwargs)
+                except BaseException as e:  # noqa: BLE001 — as above
+                    error = e
+        with span("cu.finish", cu=cu.uid):
+            try:
+                self._finish(cu, result, error, prefetcher, gen)
+            finally:
+                # gen guards the retry race: if this CU was already
+                # released and re-admitted, the stale token makes this a
+                # no-op
                 self.scheduler.release(cu, gen=gen)
-                self.scheduler.submit(cu)
                 self._wake.set()
+
+    def _finish(self, cu: ComputeUnit, result: Any,
+                error: Optional[BaseException], prefetcher,
+                gen: Optional[int]) -> None:
+        """Publish a CU's result, or retry or fail it on ``error``."""
+        # a speculation winner or a preemption may have resolved this CU
+        # while fn ran — never clobber the published result; a killed
+        # agent publishes nothing (its CUs were re-queued on survivors by
+        # the recovery — a late local completion must not race the clone
+        # that replaced it)
+        if self._killed or cu.done or cu.state is CUState.CANCELED:
+            return
+        if error is None:
+            try:
+                cu.result = result
+                cu._set_state(CUState.DONE)
+                self._record_runtime(cu)
+                self._resolve_speculation(cu)
+                # stage-out rides the same pipeline, off the critical
+                # path: the CU is DONE before the spool to GFS even starts
+                if prefetcher is not None and cu.desc.stage_out:
+                    prefetcher.request_many(
+                        cu.desc.stage_out, kind="out",
+                        priority=cu.desc.priority,
+                        reason=f"stage-out:{cu.uid}")
                 return
-            cu._set_state(CUState.FAILED)
-        finally:
-            # gen guards the retry race: if this CU was already released
-            # and re-admitted, the stale token makes this a no-op
+            except BaseException as e:  # noqa: BLE001 — as in _spawn
+                if self._killed or cu.done or cu.state is CUState.CANCELED:
+                    return
+                error = e
+        cu.error = error
+        if cu.retries < cu.desc.max_retries:
+            cu.retries += 1
+            cu._done.clear()
             self.scheduler.release(cu, gen=gen)
+            self.scheduler.submit(cu)
             self._wake.set()
+            return
+        cu._set_state(CUState.FAILED)
 
     def _launch_method(self, cu: ComputeUnit):
         """Paper: LaunchMethod encapsulates mpiexec/aprun/yarn specifics.

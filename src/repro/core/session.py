@@ -38,6 +38,7 @@ from __future__ import annotations
 
 import dataclasses
 import inspect
+import itertools
 import json
 import os
 import pickle
@@ -58,6 +59,7 @@ from .pilot import Pilot, PilotDescription, PilotManager, PilotState
 from .resource_manager import ResourceManager
 from .staging import DataRef, as_refs
 from repro.roofline.placement import StageCost, est_runtime, estimate_error
+from repro.spans import span
 
 HPC = "hpc"
 ANALYTICS = "analytics"
@@ -190,6 +192,7 @@ class Session:
         self._pre_staged: Dict[str, Tuple] = {}     # stage -> (pilot, dec, reqs)
         self._lock = threading.Lock()
         self._move_lock = threading.Lock()          # serializes input moves
+        self._dag_ids = itertools.count(1)          # the spans' ``dag``
         # session checkpoint/resume (Hadoop analogue: RM/AM restart with
         # work-preserving recovery): a periodic journal of DAG state —
         # completed stages, placements, DataPlane contents + lineage —
@@ -497,41 +500,50 @@ class Session:
     def submit_dag(self, stages: Sequence[Stage], *,
                    timeout: float = 600.0) -> Dict[str, Future]:
         """Launch the DAG; returns one future per stage (async API)."""
-        known = {s.name for s in stages} | set(self.results)
-        for s in stages:
-            bad = [a for a in s.after if a not in known]
-            if bad:
-                raise ValueError(
-                    f"stage {s.name!r} waits on unknown stage(s) {bad}")
-        self._restore_data()       # lazy half of resume (no-op otherwise)
-        deps = self._producers(stages)
-        ordered = self._topo_order(stages, deps)
-        with self._lock:
+        return self._submit_dag(stages, timeout)[1]
+
+    def _submit_dag(self, stages: Sequence[Stage], timeout: float
+                    ) -> Tuple[int, Dict[str, Future]]:
+        dag = next(self._dag_ids)
+        with span("session.dag", dag=dag, stages=len(stages)):
+            known = {s.name for s in stages} | set(self.results)
+            for s in stages:
+                bad = [a for a in s.after if a not in known]
+                if bad:
+                    raise ValueError(
+                        f"stage {s.name!r} waits on unknown stage(s) {bad}")
+            self._restore_data()   # lazy half of resume (no-op otherwise)
+            deps = self._producers(stages)
+            ordered = self._topo_order(stages, deps)
+            with self._lock:
+                for s in ordered:
+                    self._stages[s.name] = s
+            if self.prefetch:
+                self._pre_stage(ordered)
+            ex = ThreadPoolExecutor(max_workers=max(4, len(ordered)),
+                                    thread_name_prefix="session-stage")
+            futures: Dict[str, Future] = {}
             for s in ordered:
-                self._stages[s.name] = s
-        if self.prefetch:
-            self._pre_stage(ordered)
-        ex = ThreadPoolExecutor(max_workers=max(4, len(ordered)),
-                                thread_name_prefix="session-stage")
-        futures: Dict[str, Future] = {}
-        for s in ordered:
-            if s.name in self._restored_stages:
-                # resumed session: this stage completed before the crash
-                # — hand back its checkpointed result, do not re-run
-                fut: Future = Future()
-                fut.set_result(self.results.get(s.name))
-                futures[s.name] = fut
-                continue
-            dep_futs = [futures[d] for d in deps[s.name] if d in futures]
-            futures[s.name] = ex.submit(self._run_stage, s, dep_futs, timeout)
-        ex.shutdown(wait=False)
-        return futures
+                if s.name in self._restored_stages:
+                    # resumed session: this stage completed before the
+                    # crash — hand back its checkpointed result, do not
+                    # re-run
+                    fut: Future = Future()
+                    fut.set_result(self.results.get(s.name))
+                    futures[s.name] = fut
+                    continue
+                dep_futs = [futures[d] for d in deps[s.name] if d in futures]
+                futures[s.name] = ex.submit(self._run_stage, s, dep_futs,
+                                            timeout, dag)
+            ex.shutdown(wait=False)
+        return dag, futures
 
     def run(self, stages: Sequence[Stage], *,
             timeout: float = 600.0) -> Dict[str, Any]:
         """Execute the DAG to completion; returns stage name -> result."""
-        futures = self.submit_dag(stages, timeout=timeout)
-        return {name: f.result(timeout) for name, f in futures.items()}
+        dag, futures = self._submit_dag(stages, timeout)
+        with span("session.run", dag=dag):
+            return {name: f.result(timeout) for name, f in futures.items()}
 
     # ------------------------------------------------------------- staging
     def _stage_in_refs(self, stage: Stage) -> List[DataRef]:
@@ -580,65 +592,75 @@ class Session:
 
     # ------------------------------------------------------------ execution
     def _run_stage(self, stage: Stage, dep_futs: Sequence[Future],
-                   timeout: float) -> Any:
-        for f in dep_futs:                     # propagate producer failures
-            f.result(timeout)
-        ctx = self._tenants.get(stage.tenant) if stage.tenant else None
-        if ctx is not None and ctx._sem is not None:
-            # per-tenant admission: at most max_concurrent_stages in
-            # flight; excess stages wait here, not in a pilot's queue
-            if not ctx._sem.acquire(timeout=timeout):
-                raise TimeoutError(
-                    f"tenant {stage.tenant!r} admission budget "
-                    f"({ctx.max_concurrent_stages}) not freed within "
-                    f"{timeout}s for stage {stage.name!r}")
-        try:
-            with self._lock:
-                pre = self._pre_staged.pop(stage.name, None)
-            if pre is not None:
-                pilot, decision, staging = pre
-            else:
-                pilot, decision = self.place(stage)
-                staging = (self._prefetch_for(stage, pilot)
-                           if self.prefetch else None)
-            if stage.tenant:
-                decision["tenant"] = stage.tenant
-                decision["queue"] = stage.queue
-            if staging is None:
-                self._ensure_inputs_on(stage, pilot, decision)
-            t_run = time.monotonic()
-            # thread the placer's roofline estimate into the CU so the
-            # straggler watchdog has a baseline before any EMA history
-            est = decision.get("chosen", {}).get("est_runtime")
-            if stage.kind == HPC:
-                result = self._run_hpc(stage, pilot, timeout,
-                                       staging=staging, est_s=est)
-            else:
-                result = self._run_analytics(stage, pilot, decision, timeout,
-                                             staging=staging, est_s=est)
-            self._cross_check_estimate(stage, pilot, decision,
-                                       time.monotonic() - t_run)
-            if staging is not None:
-                decision["dcn_bytes_moved"] = sum(r.wire_bytes
-                                                  for r in staging)
-                decision["staging_hits"] = sum(1 for r in staging if r.hit)
-        finally:
+                   timeout: float, dag: int = 0) -> Any:
+        with span("session.stage", dag=dag, stage=stage.name):
+            with span("session.deps", dag=dag, stage=stage.name):
+                for f in dep_futs:             # propagate producer failures
+                    f.result(timeout)
+            ctx = self._tenants.get(stage.tenant) if stage.tenant else None
             if ctx is not None and ctx._sem is not None:
-                ctx._sem.release()
-        if ctx is not None:
-            ctx.stats["completed"] += 1
-        self._store_outputs(stage, pilot, result)
-        if stage.stage_out and pilot.prefetcher is not None:
-            # spool declared outputs to the GFS archive tier — off the
-            # critical path; the stage result is already published
-            pilot.prefetcher.request_many(
-                stage.stage_out, kind="out",
-                reason=f"stage-out:{stage.name}")
-        with self._lock:
-            self.results[stage.name] = result
-            self.placements[stage.name] = decision
-        self._maybe_checkpoint()
-        return result
+                # per-tenant admission: at most max_concurrent_stages in
+                # flight; excess stages wait here, not in a pilot's queue
+                if not ctx._sem.acquire(timeout=timeout):
+                    raise TimeoutError(
+                        f"tenant {stage.tenant!r} admission budget "
+                        f"({ctx.max_concurrent_stages}) not freed within "
+                        f"{timeout}s for stage {stage.name!r}")
+            try:
+                with span("session.place", stage=stage.name):
+                    with self._lock:
+                        pre = self._pre_staged.pop(stage.name, None)
+                    if pre is not None:
+                        pilot, decision, staging = pre
+                    else:
+                        pilot, decision = self.place(stage)
+                        staging = (self._prefetch_for(stage, pilot)
+                                   if self.prefetch else None)
+                if stage.tenant:
+                    decision["tenant"] = stage.tenant
+                    decision["queue"] = stage.queue
+                if staging is None:
+                    with span("session.inputs", stage=stage.name) as sp:
+                        self._ensure_inputs_on(stage, pilot, decision)
+                        sp.set_metadata(bytes=decision["dcn_bytes_moved"])
+                t_run = time.monotonic()
+                # thread the placer's roofline estimate into the CU so the
+                # straggler watchdog has a baseline before any EMA history
+                est = decision.get("chosen", {}).get("est_runtime")
+                if stage.kind == HPC:
+                    result = self._run_hpc(stage, pilot, timeout,
+                                           staging=staging, est_s=est)
+                else:
+                    result = self._run_analytics(stage, pilot, decision,
+                                                 timeout, staging=staging,
+                                                 est_s=est)
+                self._cross_check_estimate(stage, pilot, decision,
+                                           time.monotonic() - t_run)
+                if staging is not None:
+                    decision["dcn_bytes_moved"] = sum(r.wire_bytes
+                                                      for r in staging)
+                    decision["staging_hits"] = sum(1 for r in staging
+                                                   if r.hit)
+            finally:
+                if ctx is not None and ctx._sem is not None:
+                    ctx._sem.release()
+            if ctx is not None:
+                ctx.stats["completed"] += 1
+            with span("session.store", stage=stage.name) as sp:
+                sp.set_metadata(bytes=self._store_outputs(stage, pilot,
+                                                          result))
+                if stage.stage_out and pilot.prefetcher is not None:
+                    # spool declared outputs to the GFS archive tier — off
+                    # the critical path; the stage result is already
+                    # published
+                    pilot.prefetcher.request_many(
+                        stage.stage_out, kind="out",
+                        reason=f"stage-out:{stage.name}")
+                with self._lock:
+                    self.results[stage.name] = result
+                    self.placements[stage.name] = decision
+                self._maybe_checkpoint()
+            return result
 
     def _ensure_inputs_on(self, stage: Stage, pilot: Pilot,
                           decision: Dict[str, Any]) -> None:
@@ -709,14 +731,23 @@ class Session:
         def job(mesh=None):
             return stage.fn(**self._call_kwargs(stage, {"mesh": mesh}))
 
-        cu = pilot.submit(ComputeUnitDescription(
+        return self._submit_and_follow(stage, pilot, ComputeUnitDescription(
             fn=job, gang=stage.gang, n_chips=n, tag=f"stage:{stage.name}",
             data=tuple(stage.inputs), app_id=self._app_id(stage),
             tenant=stage.tenant, queue=stage.queue,
-            est_runtime_s=est_s), staging=staging)
-        # follow(): a ControlPlane drain may preempt the CU and forward
-        # to a re-queued clone — the stage result is the chain's end
-        return cu.follow(timeout)
+            est_runtime_s=est_s), staging, timeout)
+
+    @staticmethod
+    def _submit_and_follow(stage: Stage, pilot: Pilot,
+                           desc: ComputeUnitDescription,
+                           staging: Optional[Sequence],
+                           timeout: float) -> Any:
+        with span("session.wait", stage=stage.name) as sp:
+            cu = pilot.submit(desc, staging=staging)
+            sp.set_metadata(cu=cu.uid)
+            # follow(): a ControlPlane drain may preempt the CU and forward
+            # to a re-queued clone — the stage result is the chain's end
+            return cu.follow(timeout)
 
     def _run_analytics(self, stage: Stage, pilot: Pilot,
                        decision: Dict[str, Any], timeout: float,
@@ -729,15 +760,15 @@ class Session:
             def job(mesh=None):
                 return stage.fn(**self._call_kwargs(stage, {"engine": engine}))
 
-            cu = pilot.submit(ComputeUnitDescription(
-                fn=job, gang=stage.gang,
-                n_chips=stage.n_chips
-                or max(pilot.agent.scheduler.n_slots, 1),
-                tag=f"stage:{stage.name}", data=tuple(stage.inputs),
-                needs_mesh=False, app_id=self._app_id(stage),
-                tenant=stage.tenant, queue=stage.queue,
-                est_runtime_s=est_s), staging=staging)
-            return cu.follow(timeout)
+            return self._submit_and_follow(
+                stage, pilot, ComputeUnitDescription(
+                    fn=job, gang=stage.gang,
+                    n_chips=stage.n_chips
+                    or max(pilot.agent.scheduler.n_slots, 1),
+                    tag=f"stage:{stage.name}", data=tuple(stage.inputs),
+                    needs_mesh=False, app_id=self._app_id(stage),
+                    tenant=stage.tenant, queue=stage.queue,
+                    est_runtime_s=est_s), staging, timeout)
         # Mode I: carve an on-demand analytics cluster out of the HPC
         # pilot holding the data (compute goes to the data).  The carve
         # path has no CU to delay-schedule, so in-flight staging is
@@ -769,11 +800,12 @@ class Session:
                 self._engines[pilot.uid] = cached
         return cached[1]
 
-    def _store_outputs(self, stage: Stage, pilot: Pilot, result: Any) -> None:
+    def _store_outputs(self, stage: Stage, pilot: Pilot, result: Any) -> int:
         """Publish declared outputs to the DataPlane, homed on the pilot
-        that produced them, with lineage for re-materialization."""
+        that produced them, with lineage for re-materialization; returns
+        the bytes published."""
         if not stage.outputs:
-            return
+            return 0
         if isinstance(result, dict):
             pairs = [(n, result.get(n)) for n in stage.outputs]
         elif len(stage.outputs) == 1:
@@ -788,9 +820,12 @@ class Session:
                 "not return them")
         lineage = Lineage(stage=stage.name, inputs=tuple(stage.inputs))
         sharding = replicated_sharding(pilot.devices)
+        nbytes = 0
         for name, val in pairs:
             arr = jax.device_put(jnp.asarray(val), sharding)
             self.dataplane.put(name, arr, pilot=pilot.uid, lineage=lineage)
+            nbytes += arr.nbytes
+        return nbytes
 
     # ------------------------------------------------------------- recovery
     def rematerialize(self, name: str, *, timeout: float = 600.0) -> Any:

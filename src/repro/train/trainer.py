@@ -27,6 +27,7 @@ from repro.models import transformer
 from repro.models.config import ModelConfig
 from repro.optim import adamw
 from repro.sharding import Plan
+from repro.spans import span
 from repro.train.step import make_train_state, make_train_step
 
 
@@ -91,37 +92,55 @@ class Trainer:
     def run(self, n_steps: int, *, start_step: Optional[int] = None,
             log_every: int = 10, inject_failure_at: Optional[int] = None
             ) -> List[Dict[str, float]]:
-        if self.state is None:
-            if self.ckpt is not None and self.ckpt.latest_step() is not None:
-                self.restore()
-            else:
-                self.init_state()
-        step0 = (start_step if start_step is not None
-                 else int(jax.device_get(self.state["step"])))
-        self.pipeline.start(from_step=step0)
-        try:
-            with jax.set_mesh(self.mesh):
-                for i, batch in zip(range(step0, n_steps), self.pipeline):
-                    if inject_failure_at is not None and i == inject_failure_at:
-                        raise RuntimeError("injected node failure")
-                    t0 = time.monotonic()
-                    self.state, metrics = self._step(self.state, batch)
-                    metrics = {k: float(jax.device_get(v))
-                               for k, v in metrics.items()}
-                    metrics["step"] = i
-                    metrics["step_s"] = time.monotonic() - t0
-                    self.history.append(metrics)
-                    if log_every and (i % log_every == 0 or i == n_steps - 1):
-                        print(f"step {i:5d} loss {metrics['loss']:.4f} "
-                              f"gnorm {metrics['grad_norm']:.3f} "
-                              f"({metrics['step_s']*1e3:.0f} ms)")
-                    if (self.ckpt is not None and self.ckpt_every
-                            and (i + 1) % self.ckpt_every == 0):
-                        self.ckpt.save(self.state, i + 1)
-        finally:
-            self.pipeline.stop()
-            if self.ckpt is not None:
-                self.ckpt.wait()   # publish in-flight saves even on failure
-        if self.ckpt is not None:
-            self.ckpt.save(self.state, n_steps, blocking=True)
+        with span("trainer.run", steps=n_steps):
+            with span("trainer.resume"):
+                if self.state is None:
+                    if (self.ckpt is not None
+                            and self.ckpt.latest_step() is not None):
+                        self.restore()
+                    else:
+                        self.init_state()
+                step0 = (start_step if start_step is not None
+                         else int(jax.device_get(self.state["step"])))
+                self.pipeline.start(from_step=step0)
+            finished = False
+            try:
+                with jax.set_mesh(self.mesh):
+                    self._steps(step0, n_steps, log_every, inject_failure_at)
+                finished = True
+            finally:
+                with span("trainer.stop"):
+                    self.pipeline.stop()
+                    if self.ckpt is not None:
+                        # publish in-flight saves even on failure
+                        self.ckpt.wait()
+                        if finished:
+                            self.ckpt.save(self.state, n_steps, blocking=True)
         return self.history
+
+    def _steps(self, step0: int, n_steps: int, log_every: int,
+               inject_failure_at: Optional[int]) -> None:
+        batches = iter(self.pipeline)
+        for i in range(step0, n_steps):
+            with span("trainer.batch", step=i):
+                batch = next(batches, None)
+            if batch is None:
+                return
+            if inject_failure_at is not None and i == inject_failure_at:
+                raise RuntimeError("injected node failure")
+            t0 = time.monotonic()
+            with span("trainer.dispatch", step=i):
+                self.state, metrics = self._step(self.state, batch)
+            with span("trainer.sync", step=i):
+                metrics = {k: float(jax.device_get(v))
+                           for k, v in metrics.items()}
+            metrics["step"] = i
+            metrics["step_s"] = time.monotonic() - t0
+            self.history.append(metrics)
+            if log_every and (i % log_every == 0 or i == n_steps - 1):
+                print(f"step {i:5d} loss {metrics['loss']:.4f} "
+                      f"gnorm {metrics['grad_norm']:.3f} "
+                      f"({metrics['step_s']*1e3:.0f} ms)")
+            if (self.ckpt is not None and self.ckpt_every
+                    and (i + 1) % self.ckpt_every == 0):
+                self.ckpt.save(self.state, i + 1)

@@ -1,7 +1,7 @@
 """Block-size autotuner for the Pallas kernels + cached best-config registry.
 
 The kernels ship with hardcoded block sizes (``bq=256, bk=256`` for
-flash attention, fixed tiles for kmeans / mamba_scan) that leave
+flash attention, fixed blocks for kmeans / mamba_scan) that leave
 MXU/VMEM utilization on the table for shapes they were not tuned on.
 This module sweeps divisor-snapped, VMEM-budget-filtered block-size
 candidates through timed trials (the drive-one-cell shape of
@@ -36,11 +36,16 @@ KERNELS = ("flash_attention", "kmeans", "mamba_scan")
 # and the baseline every speedup is reported against
 DEFAULTS: Dict[str, Dict[str, int]] = {
     "flash_attention": {"bq": 256, "bk": 256},
-    "kmeans": {"bn": 1024, "bk": 512},
+    # points and centroids a block of the lane-dense assignment kernel
+    "kmeans": {"bn": 65536, "bk": 64},
     # bdi=512 at st=16 needs 16 MiB of VMEM for the a/b blocks alone once
     # st is padded to 128 lanes and double-buffered: v5e refuses it
     "mamba_scan": {"bdi": 256, "bs": 16},
 }
+
+# the registry's name of a kernel whose blocks changed meaning, so that an
+# entry tuned for the old blocking is never applied to the new kernel
+REGISTRY_NAMES = {"kmeans": "kmeans_planes"}
 
 # ~16 MiB scoped VMEM per TPU core; keep headroom for the compiler's own
 # scratch and the kernel body's temporaries
@@ -110,7 +115,8 @@ class Registry:
 
     @staticmethod
     def key(kernel: str, bucket: str, backend: str, dtype: str) -> str:
-        return f"{kernel}|{bucket}|{backend}|{dtype}"
+        name = REGISTRY_NAMES.get(kernel, kernel)
+        return f"{name}|{bucket}|{backend}|{dtype}"
 
     def get(self, key: str) -> Optional[Dict[str, Any]]:
         with self._lock:
@@ -210,17 +216,19 @@ def candidates_flash(S_q: int, S_k: int, hd: int,
 def candidates_kmeans(n: int, k: int, d: int,
                       budget: int = VMEM_BUDGET_BYTES
                       ) -> List[Dict[str, int]]:
-    """(bn, bk) grid for the assignment kernel.  The wrapper pads n/k up
-    to block multiples, so candidates only need the <= n/k cap, not
-    divisibility."""
+    """(bn, bk) grid for the assignment kernel, as ``resolve_blocks`` lands
+    each pair on this shape.  The VMEM estimate counts the double-buffered
+    blocks: ``d`` coordinate planes of (bn/128, 128) points, the 1-D idx
+    and distance outputs, and the centroids' scalars (SMEM, counted here
+    too as headroom)."""
+    from repro.kernels.kmeans import kmeans as km_kernel, ops as km
     out, seen = [], set()
-    for bn_w in _BLOCKS:
-        for bk_w in _BLOCKS:
-            bn = min(bn_w, _bucket(max(n, 8)))
-            bk = min(bk_w, _bucket(max(k, 8)))
-            vmem = (_f32(bn * d) + _f32(bk * d)   # point + centroid blocks
-                    + _f32(2 * bn)                # running (min, idx)
-                    + _f32(bn * bk))              # score tile
+    for bn_w in (4096, 16384, 65536, 262144):
+        for bk_w in (16, 32, 64, 128):
+            bn, bk = km.resolve_blocks(n, k, d, None, bn_w, bk_w)
+            vmem = 2 * (d * _tile_f32(bn // 128, 128)   # point planes
+                        + 2 * _f32(bn)                  # idx, distance
+                        + _f32(km_kernel.centroid_stride(d, bk)))
             if vmem > budget or (bn, bk) in seen:
                 continue
             seen.add((bn, bk))
@@ -333,8 +341,10 @@ def _resolve_default(kernel: str, shape: Dict[str, int]) -> Dict[str, int]:
         d["bdi"] = snap_block(shape["di"], d["bdi"])
         d["bs"] = snap_block(shape["S"], d["bs"])
     elif kernel == "kmeans":
-        d["bn"] = min(d["bn"], _bucket(max(shape["n"], 8)))
-        d["bk"] = min(d["bk"], _bucket(max(shape["k"], 8)))
+        from repro.kernels.kmeans import ops as km
+        d["bn"], d["bk"] = km.resolve_blocks(shape["n"], shape["k"],
+                                             shape["d"], None, d["bn"],
+                                             d["bk"])
     return d
 
 

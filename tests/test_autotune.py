@@ -134,6 +134,21 @@ def test_mamba_vmem_filter_refuses_what_v5e_refuses():
     assert at.DEFAULTS["mamba_scan"] in cands
 
 
+def test_kmeans_candidates_land_as_resolved_and_fit_vmem():
+    """Each candidate is a pair ``resolve_blocks`` keeps as it is, and the
+    d coordinate planes of the largest point block push it out at d=16."""
+    from repro.kernels.kmeans import ops as km
+    for n, k, d in ((11_184_128, 50, 3), (100_000, 500, 16), (256, 8, 3)):
+        cands = at.candidates_kmeans(n, k, d)
+        assert cands
+        for c in cands:
+            assert km.resolve_blocks(n, k, d, None, c["bn"], c["bk"]) == \
+                (c["bn"], c["bk"])
+    assert {"bn": 262144, "bk": 50} in at.candidates_kmeans(11_184_128, 50, 3)
+    assert all(c["bn"] < 262144
+               for c in at.candidates_kmeans(11_184_128, 50, 16))
+
+
 def test_candidates_snap_to_shape_divisors():
     for c in at.candidates_flash(384, 384, 64):
         assert 384 % c["bq"] == 0 and 384 % c["bk"] == 0
@@ -170,6 +185,29 @@ def test_ops_wrappers_default_without_registry(tmp_path, monkeypatch):
         (256, 256)                            # legacy constants survive
     assert ms.resolve_blocks(256, 512, 16, jnp.float32, None, None) == \
         (256, 16)                             # shipped default (v5e-legal)
+    at.default_registry(reload=True)
+
+
+def test_stale_kmeans_entry_is_not_applied(tmp_path, monkeypatch):
+    """An entry tuned for the earlier K-Means blocking, under the kernel's
+    old registry name, is never applied to the lane-dense kernel."""
+    monkeypatch.setenv("REPRO_AUTOTUNE_REGISTRY",
+                       str(tmp_path / "autotune.json"))
+    reg = at.default_registry(reload=True)
+    shape = {"n": 1_000_000, "k": 50, "d": 3}
+    bucket = at.shape_bucket("kmeans", shape)
+    reg.put(f"kmeans|{bucket}|{at.backend_tag()}|float32",
+            {"config": {"bn": 1024, "bk": 512}})
+    from repro.kernels.kmeans import ops as km
+    default = km.resolve_blocks(1_000_000, 50, 3, jnp.float32,
+                                at.DEFAULTS["kmeans"]["bn"],
+                                at.DEFAULTS["kmeans"]["bk"])
+    assert km.resolve_blocks(1_000_000, 50, 3, jnp.float32, None, None) == \
+        default
+    reg.put(at.Registry.key("kmeans", bucket, at.backend_tag(), "float32"),
+            {"config": {"bn": 16384, "bk": 32}})
+    assert km.resolve_blocks(1_000_000, 50, 3, jnp.float32, None, None) == \
+        (16384, 32)
     at.default_registry(reload=True)
 
 

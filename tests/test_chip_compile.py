@@ -9,6 +9,7 @@ at a time, and every pytest worker imports this file.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -62,16 +63,37 @@ def _compile(fn, *specs):
     return compiled
 
 
+def _assert_kmeans_results(compiled, n):
+    """The assignment kernel's custom call returns (idx s32[n], f32[n]): the
+    signature by which the benchmark finds the kernel in a device trace."""
+    assert re.search(rf"= \(s32\[{n}\]\{{[^}}]*\}}, f32\[{n}\]\{{[^}}]*\}}\) "
+                     r"custom-call\(", compiled.as_text()), n
+
+
 @pytest.mark.parametrize("n,k", [(1_000_000, 50), (1_000_000, 5_000)])
 def test_kmeans_assign_compiles(one_chip, n, k):
     """The paper's 1M x 3 scenario, with 50 and 5,000 centroids."""
     d = 3
     bn, bk = km_ops.resolve_blocks(n, k, d, jnp.float32, None, None)
-    n_pad, k_pad = -(-n // bn) * bn, -(-k // bk) * bk
-    fn = functools.partial(km_kernel.assign_pallas, bn=bn, bk=bk,
-                           interpret=False)
-    _compile(fn, _spec((n_pad, d), jnp.float32, one_chip),
-             _spec((k_pad, d), jnp.float32, one_chip))
+    rows = -(-n // km_kernel.LANES)
+    centroid_words = -(-k // bk) * km_kernel.centroid_stride(d, bk)
+    fn = functools.partial(km_kernel.assign_pallas, br=bn // km_kernel.LANES,
+                           bk=bk, interpret=False)
+    compiled = _compile(fn, _spec((d, rows, km_kernel.LANES), jnp.float32,
+                                  one_chip),
+                        _spec((centroid_words,), jnp.float32, one_chip))
+    _assert_kmeans_results(compiled, rows * km_kernel.LANES)
+
+
+def test_kmeans_block_assign_compiles(one_chip):
+    """One 128 MB HDFS block of d=3 points through the public wrapper: the
+    points are relaid out once, as coordinate planes, and no larger."""
+    n, k, d = 11_184_128, 50, 3
+    compiled = _compile(lambda p, c: km_ops.assign(p, c),
+                        _spec((n, d), jnp.float32, one_chip),
+                        _spec((k, d), jnp.float32, one_chip))
+    _assert_kmeans_results(compiled, n)
+    assert compiled.memory_analysis().temp_size_in_bytes <= 2 * n * d * 4
 
 
 @pytest.mark.parametrize("hd", [64, 128])
@@ -100,6 +122,7 @@ def test_mamba_scan_compiles(one_chip):
 def test_kmeans_ops_wrapper_picks_compiled_kernel(one_chip):
     """The public wrapper, lowered for the chip, takes the compiled branch
     (interpret mode is chosen per lowering platform, not by a global)."""
-    _compile(lambda p, c: km_ops.assign(p, c),
-             _spec((10_240, 3), jnp.float32, one_chip),
-             _spec((5_120, 3), jnp.float32, one_chip))
+    compiled = _compile(lambda p, c: km_ops.assign(p, c),
+                        _spec((10_240, 3), jnp.float32, one_chip),
+                        _spec((5_120, 3), jnp.float32, one_chip))
+    _assert_kmeans_results(compiled, 10_240)

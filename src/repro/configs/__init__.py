@@ -17,6 +17,7 @@ from . import (
     internvl2_2b,
     qwen2_moe_a2_7b,
     deepseek_v2_236b,
+    deepseek_v2_lite,
     seamless_m4t_medium,
 )
 
@@ -32,6 +33,7 @@ _REGISTRY = {
         internvl2_2b,
         qwen2_moe_a2_7b,
         deepseek_v2_236b,
+        deepseek_v2_lite,
         seamless_m4t_medium,
     )
 }
@@ -48,6 +50,7 @@ SMOKE_REGISTRY = {
         internvl2_2b,
         qwen2_moe_a2_7b,
         deepseek_v2_236b,
+        deepseek_v2_lite,
         seamless_m4t_medium,
     )
 }

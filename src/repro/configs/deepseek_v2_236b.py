@@ -3,7 +3,8 @@
 60L d_model=5120 128H, MLA kv_lora=512 q_lora=1536 (qk_nope=128,
 qk_rope=64, v_head=128), routed-expert d_ff=1536, 2 shared + 160 routed
 top-6, vocab=102400. First layer keeps a dense FFN (d_ff=12288) per the
-published config.
+published config. Routing as published: top-6 weights not renormalised
+(``norm_topk_prob`` false) and scaled by ``routed_scaling_factor`` 16.
 """
 from repro.models.config import ModelConfig
 
@@ -23,6 +24,8 @@ CONFIG = ModelConfig(
     moe_d_ff=1536,
     moe_first_k_dense=1,
     dense_d_ff=12288,
+    moe_norm_topk=False,
+    moe_routed_scale=16.0,
     use_mla=True,
     q_lora_rank=1536,
     kv_lora_rank=512,

@@ -8,7 +8,7 @@ backbones and encoder-decoder (Seamless).
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax.numpy as jnp
 
@@ -37,14 +37,25 @@ class ModelConfig:
     moe_first_k_dense: int = 0      # leading dense layers (DeepSeek-V2 style)
     dense_d_ff: int = 0             # FFN width of those dense layers
     moe_capacity_factor: float = 1.25
+    moe_norm_topk: bool = True      # renormalise the top-k weights to sum 1
+    moe_routed_scale: float = 1.0   # routed output x this (HF routed_scaling_factor)
+    moe_experts_held: int = 0       # routed experts whose weights a layer holds (0: all)
+    moe_first_expert: int = 0       # router id of the first expert held
+    moe_aux_coef: float = 0.01      # weight of the balance loss in the training loss
+    moe_seq_aux: bool = False       # per-sequence expert balance loss (DeepSeek-V2)
 
     # --- MLA (DeepSeek-V2) ---
     use_mla: bool = False
-    q_lora_rank: int = 0
+    q_lora_rank: int = 0            # 0: one query projection, no query latent
     kv_lora_rank: int = 0
     qk_nope_dim: int = 0
     qk_rope_dim: int = 0
     v_head_dim: int = 0
+
+    # --- rope scaling: the published config's ``rope_scaling`` items as
+    # (key, value) pairs; () = plain rope. Type "yarn" only
+    # (DeepseekV2YarnRotaryEmbedding). ---
+    rope_scaling: Tuple[Tuple[str, Any], ...] = ()
 
     # --- SSM (Mamba-1) ---
     ssm_d_state: int = 0
@@ -91,6 +102,21 @@ class ModelConfig:
         return _round_up(self.moe_n_routed, 16)
 
     @property
+    def yarn(self) -> Optional[Dict[str, Any]]:
+        """The YaRN rope settings, or None for plain rope."""
+        if not self.rope_scaling:
+            return None
+        rs = dict(self.rope_scaling)
+        if rs.get("type") != "yarn":
+            raise ValueError(f"unsupported rope_scaling {rs!r}")
+        return rs
+
+    @property
+    def moe_n_held(self) -> int:
+        """Routed experts whose weights each MoE layer holds."""
+        return self.moe_experts_held or self.moe_n_routed_padded
+
+    @property
     def ssm_d_inner(self) -> int:
         return self.ssm_expand * self.d_model
 
@@ -122,9 +148,11 @@ class ModelConfig:
 
         def attn_params() -> int:
             if self.use_mla:
-                p = d * self.q_lora_rank + self.q_lora_rank * self.n_heads * (
-                    self.qk_nope_dim + self.qk_rope_dim
-                )
+                qk = self.n_heads * (self.qk_nope_dim + self.qk_rope_dim)
+                if self.q_lora_rank:
+                    p = d * self.q_lora_rank + self.q_lora_rank * qk
+                else:
+                    p = d * qk
                 p += d * (self.kv_lora_rank + self.qk_rope_dim)
                 p += self.kv_lora_rank * self.n_heads * (self.qk_nope_dim + self.v_head_dim)
                 p += self.n_heads * self.v_head_dim * d
@@ -153,24 +181,17 @@ class ModelConfig:
         elif self.family == "hybrid":
             per_layer = attn_params() + ssm_params() + mlp_params(self.d_ff)
         elif self.family == "moe":
-            moe = (
-                self.moe_n_routed * mlp_params(self.moe_d_ff) / d * d  # routed
-                + self.moe_n_shared * mlp_params(self.moe_d_ff)
-                + d * self.moe_n_routed  # router
-            )
-            per_layer = attn_params() + int(moe)
+            held = self.moe_experts_held or self.moe_n_routed
+            per_layer = (attn_params()
+                         + (held + self.moe_n_shared) * mlp_params(self.moe_d_ff)
+                         + d * self.moe_n_routed)  # router over every expert
         else:
             per_layer = attn_params() + mlp_params(self.d_ff)
 
         n += self.n_layers * per_layer
-        if self.moe_first_k_dense:
+        if self.moe_first_k_dense:  # leading layers hold a dense FFN instead
             n += self.moe_first_k_dense * (
-                attn_params() + mlp_params(self.dense_d_ff)
-                - per_layer + attn_params() + 0
-            )
-            # first-k layers replace MoE FFN with a dense one:
-            n += self.moe_first_k_dense * (mlp_params(self.dense_d_ff))
-            n -= self.moe_first_k_dense * 0
+                attn_params() + mlp_params(self.dense_d_ff) - per_layer)
         if self.is_encoder_decoder:
             # encoder layers: self-attn + mlp; decoder adds cross-attn
             enc = self.n_encoder_layers * (attn_params() + mlp_params(self.d_ff))
@@ -182,11 +203,11 @@ class ModelConfig:
         """Active parameters per token (for MoE MODEL_FLOPS = 6*N_active*D)."""
         if self.family != "moe":
             return self.n_params()
-        d = self.d_model
-        full = self.n_params()
-        routed_all = self.n_layers * self.moe_n_routed * 3 * d * self.moe_d_ff
-        routed_active = self.n_layers * self.moe_top_k * 3 * d * self.moe_d_ff
-        return int(full - routed_all + routed_active)
+        held = self.moe_experts_held or self.moe_n_routed
+        active = min(self.moe_top_k, held)
+        moe_layers = self.n_layers - self.moe_first_k_dense
+        return int(self.n_params() - moe_layers * (held - active) * 3
+                   * self.d_model * self.moe_d_ff)
 
 
 @dataclasses.dataclass(frozen=True)

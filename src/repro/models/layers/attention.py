@@ -17,7 +17,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .common import apply_rope, normal_init, rope_angles
+from .common import apply_rope, normal_init, rope_angles, yarn_angles, yarn_mscale
 
 Params = Dict[str, Any]
 
@@ -64,11 +64,14 @@ def _mask_bias(q_pos: jax.Array, k_pos: jax.Array, causal: bool, window: int,
 
 def sdpa(q: jax.Array, k: jax.Array, v: jax.Array, q_pos: jax.Array,
          k_pos: jax.Array, *, causal: bool, window: int = 0,
-         k_valid: Optional[jax.Array] = None) -> jax.Array:
-    """Full-materialization attention. q: (B,Sq,H,hd), k/v: (B,Sk,H,hd)."""
+         k_valid: Optional[jax.Array] = None,
+         scale: Optional[float] = None) -> jax.Array:
+    """Full-materialization attention. q: (B,Sq,H,hd), k/v: (B,Sk,H,hd).
+    ``scale`` multiplies the scores (default hd ** -0.5)."""
     hd = q.shape[-1]
+    scale = hd ** -0.5 if scale is None else scale
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
-    logits = logits * (hd ** -0.5) + _mask_bias(q_pos, k_pos, causal, window, k_valid)
+    logits = logits * scale + _mask_bias(q_pos, k_pos, causal, window, k_valid)
     w = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", w, v)
 
@@ -76,7 +79,8 @@ def sdpa(q: jax.Array, k: jax.Array, v: jax.Array, q_pos: jax.Array,
 def chunked_sdpa(q: jax.Array, k: jax.Array, v: jax.Array, q_pos: jax.Array,
                  k_pos: jax.Array, *, causal: bool, window: int = 0,
                  k_valid: Optional[jax.Array] = None,
-                 q_chunk: int = Q_CHUNK, kv_chunk: int = KV_CHUNK) -> jax.Array:
+                 q_chunk: int = Q_CHUNK, kv_chunk: int = KV_CHUNK,
+                 scale: Optional[float] = None) -> jax.Array:
     """Online-softmax chunked attention; memory O(q_chunk * kv_chunk).
 
     Note: block-masked (compute over all block pairs) — the Pallas flash
@@ -87,7 +91,7 @@ def chunked_sdpa(q: jax.Array, k: jax.Array, v: jax.Array, q_pos: jax.Array,
     Sk = k.shape[1]
     nq, nk = Sq // q_chunk, Sk // kv_chunk
     assert Sq % q_chunk == 0 and Sk % kv_chunk == 0, (Sq, Sk, q_chunk, kv_chunk)
-    scale = hd ** -0.5
+    scale = hd ** -0.5 if scale is None else scale
 
     qc = q.reshape(B, nq, q_chunk, H, hd).swapaxes(0, 1)        # (nq,B,qc,H,hd)
     qp = q_pos.reshape(nq, q_chunk)
@@ -231,41 +235,68 @@ def gqa_decode(cfg, p: Params, x: jax.Array, cache: Dict[str, jax.Array],
 
 # =================================================================== MLA
 def init_mla(cfg, key) -> Params:
+    """MLA weights; with ``q_lora_rank`` 0 (DeepSeek-V2-Lite) the query is
+    one projection ``w_q`` with no latent and no ``q_norm``."""
     d, h = cfg.d_model, cfg.n_heads
     qr, kvr = cfg.q_lora_rank, cfg.kv_lora_rank
     nope, rope, vh = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
     dt = cfg.param_dtype
     ks = jax.random.split(key, 6)
-    return {
-        "w_dq": normal_init(ks[0], (d, qr), dt, d ** -0.5),
-        "w_uq": normal_init(ks[1], (qr, h, nope + rope), dt, qr ** -0.5),
+    p = {
         "w_dkv": normal_init(ks[2], (d, kvr + rope), dt, d ** -0.5),
         "w_uk": normal_init(ks[3], (kvr, h, nope), dt, kvr ** -0.5),
         "w_uv": normal_init(ks[4], (kvr, h, vh), dt, kvr ** -0.5),
         "wo": normal_init(ks[5], (h, vh, d), dt, (h * vh) ** -0.5),
-        "q_norm": jnp.ones((qr,), jnp.float32),
         "kv_norm": jnp.ones((kvr,), jnp.float32),
     }
+    if qr:
+        p["w_dq"] = normal_init(ks[0], (d, qr), dt, d ** -0.5)
+        p["w_uq"] = normal_init(ks[1], (qr, h, nope + rope), dt, qr ** -0.5)
+        p["q_norm"] = jnp.ones((qr,), jnp.float32)
+    else:
+        p["w_q"] = normal_init(ks[0], (d, h, nope + rope), dt, d ** -0.5)
+    return p
+
+
+def _mla_rope(cfg, positions):
+    """cos/sin of the rope dims: YaRN's when the config scales rope."""
+    if cfg.rope_scaling:
+        return yarn_angles(cfg, positions, cfg.qk_rope_dim)
+    return rope_angles(positions, cfg.qk_rope_dim, cfg.rope_theta)
+
+
+def mla_softmax_scale(cfg) -> float:
+    """(nope + rope) ** -0.5, times mscale(f, mscale_all_dim) ** 2 under
+    YaRN, as the published attention sets ``softmax_scale``."""
+    scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+    rs = cfg.yarn
+    if rs and rs.get("mscale_all_dim"):
+        scale *= yarn_mscale(rs["factor"], rs["mscale_all_dim"]) ** 2
+    return scale
 
 
 def _mla_q(cfg, p, x, positions):
     from .common import rmsnorm
-    nope, rope = cfg.qk_nope_dim, cfg.qk_rope_dim
-    q_lat = rmsnorm({"scale": p["q_norm"]}, jnp.einsum("bsd,dr->bsr", x, p["w_dq"]))
-    q = jnp.einsum("bsr,rhk->bshk", q_lat, p["w_uq"])
+    nope = cfg.qk_nope_dim
+    if cfg.q_lora_rank:
+        q_lat = rmsnorm({"scale": p["q_norm"]},
+                        jnp.einsum("bsd,dr->bsr", x, p["w_dq"]), cfg.norm_eps)
+        q = jnp.einsum("bsr,rhk->bshk", q_lat, p["w_uq"])
+    else:
+        q = jnp.einsum("bsd,dhk->bshk", x, p["w_q"])
     q_nope, q_rope = q[..., :nope], q[..., nope:]
-    cos, sin = rope_angles(positions, rope, cfg.rope_theta)
+    cos, sin = _mla_rope(cfg, positions)
     q_rope = apply_rope(q_rope, cos, sin)
     return q_nope, q_rope
 
 
 def _mla_latent(cfg, p, x, positions):
     from .common import rmsnorm
-    kvr, rope = cfg.kv_lora_rank, cfg.qk_rope_dim
+    kvr = cfg.kv_lora_rank
     lat = jnp.einsum("bsd,dr->bsr", x, p["w_dkv"])
-    ckv = rmsnorm({"scale": p["kv_norm"]}, lat[..., :kvr])
+    ckv = rmsnorm({"scale": p["kv_norm"]}, lat[..., :kvr], cfg.norm_eps)
     k_rope = lat[..., kvr:][:, :, None, :]  # single shared rope head
-    cos, sin = rope_angles(positions, rope, cfg.rope_theta)
+    cos, sin = _mla_rope(cfg, positions)
     k_rope = apply_rope(k_rope, cos, sin)[:, :, 0, :]
     return ckv, k_rope
 
@@ -288,9 +319,10 @@ def mla_forward(cfg, p: Params, x: jax.Array, positions: jax.Array,
     if S > CHUNK_THRESHOLD:
         vp = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, q.shape[-1] - vh)))
         out = chunked_sdpa(q, k, vp, positions, positions, causal=True,
-                           k_valid=k_valid)[..., :vh]
+                           k_valid=k_valid, scale=mla_softmax_scale(cfg))[..., :vh]
     else:
-        out = sdpa(q, k, v, positions, positions, causal=True, k_valid=k_valid)
+        out = sdpa(q, k, v, positions, positions, causal=True, k_valid=k_valid,
+                   scale=mla_softmax_scale(cfg))
     cache = {"ckv": ckv, "k_rope": k_rope}
     return jnp.einsum("bshk,hkd->bsd", out, p["wo"]), cache
 
@@ -323,7 +355,7 @@ def mla_decode(cfg, p: Params, x: jax.Array, cache: Dict[str, jax.Array],
     q_lat = jnp.einsum("bshk,rhk->bshr", q_nope, p["w_uk"])
     logits = jnp.einsum("bshr,btr->bhst", q_lat, cache["ckv"]).astype(jnp.float32)
     logits += jnp.einsum("bshk,btk->bhst", q_rope, cache["k_rope"]).astype(jnp.float32)
-    logits *= (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+    logits *= mla_softmax_scale(cfg)
     valid = jnp.arange(Sc)[None, :] <= pos[:, None]
     if start is not None:
         valid &= jnp.arange(Sc)[None, :] >= start[:, None]

@@ -1,10 +1,12 @@
 """Shared model primitives: norms, RoPE, SwiGLU MLP, embeddings."""
 from __future__ import annotations
 
+import math
 from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 Params = Dict[str, Any]
 
@@ -38,6 +40,48 @@ def rope_angles(positions: jax.Array, head_dim: int, theta: float) -> tuple[jax.
     freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
     ang = positions.astype(jnp.float32)[..., None] * freqs  # (..., half)
     return jnp.cos(ang), jnp.sin(ang)
+
+
+def yarn_mscale(scale: float, mscale: float) -> float:
+    """YaRN's attention temperature factor (``yarn_get_mscale``)."""
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_inv_freq(head_dim: int, theta: float, factor: float, original: int,
+                  beta_fast: float, beta_slow: float) -> np.ndarray:
+    """``DeepseekV2YarnRotaryEmbedding``'s frequencies: the plain ones
+    below the correction range (dims rotating more than ``beta_fast``
+    times over ``original`` positions), the plain ones / ``factor`` above
+    it (fewer than ``beta_slow``), blended linearly in between."""
+    half = head_dim // 2
+    plain = 1.0 / theta ** (np.arange(half, dtype=np.float64) * 2 / head_dim)
+
+    def dim_of(rotations):
+        return (head_dim * math.log(original / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(dim_of(beta_fast)), 0)
+    high = min(math.ceil(dim_of(beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(half) - low) / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp                     # 1: plain frequency, 0: interpolated
+    return (plain / factor * (1.0 - keep) + plain * keep).astype(np.float32)
+
+
+def yarn_angles(cfg, positions: jax.Array, head_dim: int
+                ) -> tuple[jax.Array, jax.Array]:
+    """cos/sin at YaRN's frequencies, times its cos/sin factor
+    mscale(factor, mscale) / mscale(factor, mscale_all_dim)."""
+    rs = cfg.yarn
+    f = rs["factor"]
+    inv = jnp.asarray(yarn_inv_freq(head_dim, cfg.rope_theta, f,
+                                    rs["original_max_position_embeddings"],
+                                    rs.get("beta_fast", 32),
+                                    rs.get("beta_slow", 1)))
+    m = (yarn_mscale(f, rs.get("mscale", 1))
+         / yarn_mscale(f, rs.get("mscale_all_dim", 0)))
+    ang = positions.astype(jnp.float32)[..., None] * inv
+    return jnp.cos(ang) * m, jnp.sin(ang) * m
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:

@@ -137,14 +137,17 @@ def _mixer_forward(cfg, seg: Segment, p: Params, x, positions,
 def block_forward(cfg, seg: Segment, p: Params, x, positions, enc_out=None,
                   moe_groups: int = 1, moe_ep_axis=None, save_spec=None,
                   k_valid=None,
-                  ) -> Tuple[jax.Array, Dict[str, Any], jax.Array]:
-    """Full-sequence block. Returns (x, cache, moe_aux)."""
+                  ) -> Tuple[jax.Array, Dict[str, Any], jax.Array,
+                             Dict[str, jax.Array]]:
+    """Full-sequence block. Returns (x, cache, moe_aux, moe counters);
+    the counters are empty for a block without experts."""
     def _save(v):
         # values the save_tp_out remat policy keeps; optionally stored
         # sequence-sharded (save_spec) so 3x saved acts still fit HBM
         return checkpoint_name(_constrain(v, save_spec), "tp_out")
 
     aux = jnp.zeros((), jnp.float32)
+    stats: Dict[str, jax.Array] = {}
     dx, cache = _mixer_forward(cfg, seg, p, x, positions, k_valid=k_valid)
     x = x + _save(dx)
     if seg.cross:
@@ -160,10 +163,10 @@ def block_forward(cfg, seg: Segment, p: Params, x, positions, enc_out=None,
         if seg.ffn == "mlp":
             x = x + _save(common.mlp(p["mlp"], h))
         else:
-            out, aux = moe_lib.moe_forward(cfg, p["moe"], h, groups=moe_groups,
-                                           ep_axis=moe_ep_axis)
+            out, aux, stats = moe_lib.moe_forward(
+                cfg, p["moe"], h, groups=moe_groups, ep_axis=moe_ep_axis)
             x = x + _save(out)
-    return x, cache, aux
+    return x, cache, aux, stats
 
 
 def block_decode(cfg, seg: Segment, p: Params, x, cache: Dict[str, Any],
@@ -209,8 +212,8 @@ def block_decode(cfg, seg: Segment, p: Params, x, cache: Dict[str, Any],
         if seg.ffn == "mlp":
             x = x + common.mlp(p["mlp"], h)
         else:
-            out, _ = moe_lib.moe_forward(cfg, p["moe"], h, groups=moe_groups,
-                                         ep_axis=moe_ep_axis)
+            out, _, _ = moe_lib.moe_forward(cfg, p["moe"], h, groups=moe_groups,
+                                            ep_axis=moe_ep_axis)
             x = x + out
     return x, new_cache
 
@@ -263,8 +266,10 @@ def _run_segments(cfg, segs, seg_params, x, positions, enc_out=None, *,
                   remat: bool = True, want_cache: bool = False,
                   act_spec=None, moe_groups: int = 1, moe_ep_axis=None,
                   remat_policy=None, save_spec=None, k_valid=None):
-    """Scan each segment; returns (x, per-segment stacked caches, aux sum)."""
+    """Scan each segment; returns (x, per-segment stacked caches, aux sum,
+    MoE counters summed over layers)."""
     caches, aux_total = [], jnp.zeros((), jnp.float32)
+    stats_total: Dict[str, jax.Array] = {}
     for seg, sp in zip(segs, seg_params):
         def body(carry, lp, seg=seg):
             # barrier: stops XLA from hoisting a convert of the *stacked*
@@ -272,21 +277,23 @@ def _run_segments(cfg, segs, seg_params, x, positions, enc_out=None, *,
             # materialize a whole-model f32 activation copy)
             carry = jax.lax.optimization_barrier(carry)
             carry = _grad_dtype_guard(carry)
-            y, cache, aux = block_forward(cfg, seg, lp, carry, positions,
-                                          enc_out, moe_groups, moe_ep_axis,
-                                          save_spec, k_valid)
+            y, cache, aux, stats = block_forward(
+                cfg, seg, lp, carry, positions, enc_out, moe_groups,
+                moe_ep_axis, save_spec, k_valid)
             y = _constrain(y, act_spec)
             if not want_cache:  # keep k/v tensors out of the jaxpr for training
                 cache = {}
-            return y, (cache, aux)
+            return y, (cache, aux, stats)
 
         if remat:
             body = jax.checkpoint(body, prevent_cse=False,
                                   policy=REMAT_POLICIES.get(remat_policy))
-        x, (cache, aux) = jax.lax.scan(body, x, sp)
+        x, (cache, aux, stats) = jax.lax.scan(body, x, sp)
         caches.append(cache)
         aux_total = aux_total + aux.sum()
-    return x, caches, aux_total
+        for k, v in stats.items():
+            stats_total[k] = stats_total.get(k, 0.0) + v.sum()
+    return x, caches, aux_total, stats_total
 
 
 def embed_inputs(cfg: ModelConfig, params: Params, batch: Dict[str, jax.Array]) -> jax.Array:
@@ -307,15 +314,15 @@ def forward(cfg: ModelConfig, params: Params, batch: Dict[str, jax.Array], *,
         enc_x = batch["frame_embeds"].astype(cfg.param_dtype)
         enc_pos = jnp.arange(enc_x.shape[1])
         enc_segs = build_segments(cfg, role="encoder")
-        enc_out, _, _ = _run_segments(cfg, enc_segs, params["enc_segments"],
-                                      enc_x, enc_pos, remat=remat,
-                                      act_spec=act_spec)
+        enc_out, _, _, _ = _run_segments(cfg, enc_segs, params["enc_segments"],
+                                         enc_x, enc_pos, remat=remat,
+                                         act_spec=act_spec)
         enc_out = common.rmsnorm(params["enc_final_norm"], enc_out, cfg.norm_eps)
     x = embed_inputs(cfg, params, batch)
     positions = jnp.arange(x.shape[1])
-    x, _, aux = _run_segments(cfg, segs, params["segments"], x, positions,
-                              enc_out, remat=remat, act_spec=act_spec,
-                              moe_groups=moe_groups, moe_ep_axis=moe_ep_axis)
+    x, _, aux, _ = _run_segments(cfg, segs, params["segments"], x, positions,
+                                 enc_out, remat=remat, act_spec=act_spec,
+                                 moe_groups=moe_groups, moe_ep_axis=moe_ep_axis)
     x = common.rmsnorm(params["final_norm"], x, cfg.norm_eps)
     return common.unembed(cfg, params, x), aux
 
@@ -325,30 +332,43 @@ LOSS_CHUNK = 512  # sequence-chunked CE above this length (memory-linear)
 
 def _hidden_states(cfg, params, batch, *, remat, act_spec, moe_groups=1,
                    moe_ep_axis=None, remat_policy=None, save_spec=None):
-    """Forward to final hidden states (pre-unembed)."""
+    """Forward to final hidden states (pre-unembed), the aux loss and the
+    MoE counters."""
     segs = build_segments(cfg)
     enc_out = None
     if cfg.is_encoder_decoder:
         enc_x = batch["frame_embeds"].astype(cfg.param_dtype)
         enc_segs = build_segments(cfg, role="encoder")
-        enc_out, _, _ = _run_segments(cfg, enc_segs, params["enc_segments"],
-                                      enc_x, jnp.arange(enc_x.shape[1]),
-                                      remat=remat, act_spec=act_spec)
+        enc_out, _, _, _ = _run_segments(cfg, enc_segs, params["enc_segments"],
+                                         enc_x, jnp.arange(enc_x.shape[1]),
+                                         remat=remat, act_spec=act_spec)
         enc_out = common.rmsnorm(params["enc_final_norm"], enc_out, cfg.norm_eps)
     x = embed_inputs(cfg, params, batch)
     positions = jnp.arange(x.shape[1])
-    x, _, aux = _run_segments(cfg, segs, params["segments"], x, positions,
-                              enc_out, remat=remat, act_spec=act_spec,
-                              moe_groups=moe_groups, moe_ep_axis=moe_ep_axis,
-                              remat_policy=remat_policy, save_spec=save_spec)
-    return common.rmsnorm(params["final_norm"], x, cfg.norm_eps), aux
+    x, _, aux, stats = _run_segments(
+        cfg, segs, params["segments"], x, positions, enc_out, remat=remat,
+        act_spec=act_spec, moe_groups=moe_groups, moe_ep_axis=moe_ep_axis,
+        remat_policy=remat_policy, save_spec=save_spec)
+    return common.rmsnorm(params["final_norm"], x, cfg.norm_eps), aux, stats
 
 
-def loss_fn(cfg: ModelConfig, params: Params, batch: Dict[str, jax.Array], *,
-            aux_coef: float = 0.01, remat: bool = True,
-            act_spec=None, moe_groups: int = 1, moe_ep_axis=None,
-            remat_policy=None, save_spec=None) -> jax.Array:
-    x, aux = _hidden_states(cfg, params, batch, remat=remat, act_spec=act_spec,
+def loss_fn(cfg: ModelConfig, params: Params, batch: Dict[str, jax.Array],
+            **kw) -> jax.Array:
+    """Mean next-token cross-entropy plus the weighted MoE aux loss."""
+    return loss_and_stats(cfg, params, batch, **kw)[0]
+
+
+def loss_and_stats(cfg: ModelConfig, params: Params,
+                   batch: Dict[str, jax.Array], *,
+                   aux_coef: Optional[float] = None, remat: bool = True,
+                   act_spec=None, moe_groups: int = 1, moe_ep_axis=None,
+                   remat_policy=None, save_spec=None
+                   ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """(loss, MoE counters summed over layers; empty without experts).
+    ``aux_coef`` defaults to the config's ``moe_aux_coef``."""
+    if aux_coef is None:
+        aux_coef = cfg.moe_aux_coef
+    x, aux, stats = _hidden_states(cfg, params, batch, remat=remat, act_spec=act_spec,
                             moe_groups=moe_groups, moe_ep_axis=moe_ep_axis,
                             remat_policy=remat_policy, save_spec=save_spec)
     labels, mask = batch["labels"], batch["mask"].astype(jnp.float32)
@@ -380,7 +400,7 @@ def loss_fn(cfg: ModelConfig, params: Params, batch: Dict[str, jax.Array], *,
     else:
         logits = common.unembed(cfg, params, x)
         nll = common.softmax_cross_entropy(logits, labels, mask)
-    return nll + aux_coef * aux
+    return nll + aux_coef * aux, stats
 
 
 # ------------------------------------------------------------------ serving
@@ -432,16 +452,17 @@ def prefill(cfg: ModelConfig, params: Params, batch: Dict[str, jax.Array],
     if cfg.is_encoder_decoder:
         enc_x = batch["frame_embeds"].astype(cfg.param_dtype)
         enc_segs = build_segments(cfg, role="encoder")
-        enc_out, _, _ = _run_segments(cfg, enc_segs, params["enc_segments"],
-                                      enc_x, jnp.arange(enc_x.shape[1]), remat=False)
+        enc_out, _, _, _ = _run_segments(cfg, enc_segs, params["enc_segments"],
+                                         enc_x, jnp.arange(enc_x.shape[1]),
+                                         remat=False)
         enc_out = common.rmsnorm(params["enc_final_norm"], enc_out, cfg.norm_eps)
     x = embed_inputs(cfg, params, batch)
     if positions is None:
         positions = jnp.arange(x.shape[1])
-    x, caches, _ = _run_segments(cfg, segs, params["segments"], x, positions,
-                                 enc_out, remat=False, want_cache=True,
-                                 moe_groups=moe_groups, moe_ep_axis=moe_ep_axis,
-                                 k_valid=pad_mask)
+    x, caches, _, _ = _run_segments(cfg, segs, params["segments"], x, positions,
+                                    enc_out, remat=False, want_cache=True,
+                                    moe_groups=moe_groups,
+                                    moe_ep_axis=moe_ep_axis, k_valid=pad_mask)
     x = common.rmsnorm(params["final_norm"], x, cfg.norm_eps)
     logits = common.unembed(cfg, params, x[:, -1:, :])
     # prefill caches for windowed segments keep only the trailing window

@@ -35,7 +35,10 @@ def _attn_flops_mla(cfg: ModelConfig, B: int, S: int, S_kv: int,
     d, h = cfg.d_model, cfg.n_heads
     qr, kvr = cfg.q_lora_rank, cfg.kv_lora_rank
     nope, rope, vh = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
-    f = 2 * B * S * d * qr + 2 * B * S * qr * h * (nope + rope)      # q path
+    if qr:
+        f = 2 * B * S * d * qr + 2 * B * S * qr * h * (nope + rope)  # q path
+    else:
+        f = 2 * B * S * d * h * (nope + rope)                        # q proj
     f += 2 * B * S * d * (kvr + rope)                                # latent
     if decode_absorbed:
         f += 2 * B * S * h * nope * kvr                              # q absorb
@@ -60,7 +63,7 @@ def _moe_flops(cfg: ModelConfig, B: int, S: int) -> float:
     cap = max(8, ((int(-(-cfg.moe_capacity_factor * T * cfg.moe_top_k // e)) + 7)
                   // 8) * 8)
     router = 2 * T * d * e
-    experts = 3 * 2 * e * cap * d * cfg.moe_d_ff
+    experts = 3 * 2 * cfg.moe_n_held * cap * d * cfg.moe_d_ff   # held ones
     shared = _mlp_flops(cfg, B, S, cfg.moe_n_shared * cfg.moe_d_ff)
     return router + experts + shared
 

@@ -35,6 +35,7 @@ _ROLE_TABLE: Dict[str, Tuple[Optional[str], ...]] = {
     "wv": ("fsdp", "tp", None),
     "wo": ("tp", None, "fsdp"),
     # MLA (latent dims FSDP-sharded for storage; XLA gathers at use)
+    "w_q": ("fsdp", "tp", None),
     "w_dq": ("fsdp", "tp"),
     "w_uq": ("fsdp", "tp", None),
     "w_dkv": ("fsdp", "tp"),

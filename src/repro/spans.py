@@ -22,6 +22,12 @@ waited.  The arguments link spans across threads:
 * ``bytes`` bytes moved onto a pilot or published to the DataPlane;
 * ``steps`` the step a ``Trainer.run`` call runs up to; ``stages`` the
   DAG's stage count; ``bound`` the CUs an agent round bound.
+
+Counters beside the spans: the train step's metrics (``Trainer.history``,
+read back each step under ``trainer.sync``) carry, for a configuration
+with experts only, ``moe_pairs`` (the (token, expert) pairs the layer's
+held experts computed, summed over layers and microbatches) and
+``moe_dropped`` (the pairs routed to them that the capacity dropped).
 """
 from __future__ import annotations
 

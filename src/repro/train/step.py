@@ -51,23 +51,29 @@ def microbatch_count(cfg: ModelConfig, global_batch: int, seq: int,
 def make_train_step(cfg: ModelConfig, *, hyper: adamw.Hyper = adamw.Hyper(),
                     n_microbatches: int = 1, remat: bool = True,
                     act_spec=None, lr_schedule=None,
-                    aux_coef: float = 0.01, moe_groups: int = 1,
+                    aux_coef: Optional[float] = None, moe_groups: int = 1,
                     moe_ep_axis=None, accum_dtype=jnp.float32,
                     remat_policy=None, save_spec=None):
-    """Build the (state, batch) -> (state, metrics) step function."""
+    """Build the (state, batch) -> (state, metrics) step function.
+
+    ``aux_coef`` defaults to the config's ``moe_aux_coef``.  The metrics of
+    a config with experts also carry its MoE counters (``moe_pairs``,
+    ``moe_dropped``), summed over layers and microbatches."""
     lr_schedule = lr_schedule or (lambda s: schedule.warmup_cosine(s))
 
     def loss_of(params, mb):
-        return transformer.loss_fn(cfg, params, mb, aux_coef=aux_coef,
-                                   remat=remat, act_spec=act_spec,
-                                   moe_groups=moe_groups,
-                                   moe_ep_axis=moe_ep_axis,
-                                   remat_policy=remat_policy,
-                                   save_spec=save_spec)
+        return transformer.loss_and_stats(cfg, params, mb, aux_coef=aux_coef,
+                                          remat=remat, act_spec=act_spec,
+                                          moe_groups=moe_groups,
+                                          moe_ep_axis=moe_ep_axis,
+                                          remat_policy=remat_policy,
+                                          save_spec=save_spec)
 
     def grads_of(params, batch):
         if n_microbatches == 1:
-            return jax.value_and_grad(loss_of)(params, batch)
+            (l, stats), g = jax.value_and_grad(loss_of, has_aux=True)(
+                params, batch)
+            return l, stats, g
 
         def split(x):
             b = x.shape[0]
@@ -79,22 +85,25 @@ def make_train_step(cfg: ModelConfig, *, hyper: adamw.Hyper = adamw.Hyper(),
 
         def acc(carry, mb):
             tot_l, tot_g = carry
-            l, g = jax.value_and_grad(loss_of)(params, mb)
+            (l, stats), g = jax.value_and_grad(loss_of, has_aux=True)(
+                params, mb)
             tot_g = jax.tree.map(lambda a, b: a + b.astype(accum_dtype), tot_g, g)
-            return (tot_l + l, tot_g), None
+            return (tot_l + l, tot_g), stats
 
-        (l, g), _ = jax.lax.scan(acc, (jnp.zeros(()), g0), mbs)
+        (l, g), stats = jax.lax.scan(acc, (jnp.zeros(()), g0), mbs)
         inv = 1.0 / n_microbatches
-        return l * inv, jax.tree.map(lambda x: x * inv, g)
+        stats = {k: v.sum() for k, v in stats.items()}
+        return l * inv, stats, jax.tree.map(lambda x: x * inv, g)
 
     def train_step(state: TrainState, batch: Dict[str, jax.Array],
                    ) -> Tuple[TrainState, Dict[str, jax.Array]]:
-        loss, grads = grads_of(state["params"], batch)
+        loss, stats, grads = grads_of(state["params"], batch)
         lr_scale = lr_schedule(state["step"])
         new_p, new_opt, om = adamw.update(state["params"], grads, state["opt"],
                                           state["step"], hyper, lr_scale)
         new_state = {"params": new_p, "opt": new_opt, "step": state["step"] + 1}
-        metrics = {"loss": loss, "lr_scale": jnp.asarray(lr_scale), **om}
+        metrics = {"loss": loss, "lr_scale": jnp.asarray(lr_scale), **om,
+                   **stats}
         return new_state, metrics
 
     return train_step

@@ -1,21 +1,35 @@
-"""Attention layers: GQA (full / sliding-window / chunked-flash) and MLA.
+"""Attention layers: GQA (full / sliding-window / chunked) and MLA.
 
-Sharding notes (see sharding/planner.py):
-  * q/o projections are sharded on the head axis when n_heads divides the
-    model axis; k/v projections are replicated when n_kv_heads doesn't
-    divide it (they are small). The attention einsum uses the repeat-kv
-    form so all S^2 compute is sharded on the (repeated) head axis.
-  * Long sequences (> CHUNK_THRESHOLD) use a chunked online-softmax
-    ("flash in jnp") path so the dry-run memory analysis reflects a
-    memory-linear attention; the Pallas flash kernel (kernels/flash
-    _attention) is the TPU hot-spot implementation of the same math.
+Full-sequence attention (training, prefill) takes one of two paths:
+
+  * ``causal_attention``: the fused causal kernel (the splash attention
+    kernel bundled with JAX), forward and backward, on the TPU.  It never
+    writes the scores to HBM, skips the blocks the causal mask empties,
+    and serves each group of query heads from its one K/V head, which is
+    never repeated.
+    ``gqa_forward`` and ``mla_forward`` take it where
+    ``fused_attention_applies`` holds (causal, no window, no padding
+    mask, self-attention, S a multiple of a block, batch and heads on one
+    device) and the call is lowered for a TPU.
+  * ``sdpa`` / ``chunked_sdpa`` everywhere else: on the CPU, for sliding
+    windows, padded prefill (``k_valid``), cross-attention and meshes of
+    more than one device.  Sequences above CHUNK_THRESHOLD use the
+    chunked online-softmax form, so memory stays linear in S.
+
+Sharding notes (see sharding/planner.py): q/o projections are sharded on
+the head axis when n_heads divides the model axis; k/v projections are
+replicated when n_kv_heads doesn't divide it (they are small).  The
+``sdpa`` paths use the repeat-kv form so all S^2 compute is sharded on
+the (repeated) head axis.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
+import functools
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu import splash_attention as splash
 
 from .common import apply_rope, normal_init, rope_angles, yarn_angles, yarn_mscale
 
@@ -25,6 +39,8 @@ CHUNK_THRESHOLD = 2048  # use chunked attention above this sequence length
 Q_CHUNK = 1024
 KV_CHUNK = 1024
 NEG_INF = -1e30
+FUSED_BLOCK = 512      # the fused kernel's query block (K/V: up to twice it)
+FUSED_MIN_BLOCK = 128  # the least block the kernel tiles (one lane row)
 
 
 # =================================================================== GQA
@@ -82,13 +98,14 @@ def chunked_sdpa(q: jax.Array, k: jax.Array, v: jax.Array, q_pos: jax.Array,
                  q_chunk: int = Q_CHUNK, kv_chunk: int = KV_CHUNK,
                  scale: Optional[float] = None) -> jax.Array:
     """Online-softmax chunked attention; memory O(q_chunk * kv_chunk).
+    v may have a head width of its own (MLA's 128 beside q.k's 192).
 
-    Note: block-masked (compute over all block pairs) — the Pallas flash
-    kernel skips fully-masked blocks on TPU; HLO FLOPs here include that
-    causal slack (accounted in the roofline notes).
+    Note: block-masked (compute over all block pairs) — the fused kernel
+    (``causal_attention``) skips fully-masked blocks on TPU; HLO FLOPs
+    here include that causal slack (accounted in the roofline notes).
     """
     B, Sq, H, hd = q.shape
-    Sk = k.shape[1]
+    Sk, dv = k.shape[1], v.shape[-1]
     nq, nk = Sq // q_chunk, Sk // kv_chunk
     assert Sq % q_chunk == 0 and Sk % kv_chunk == 0, (Sq, Sk, q_chunk, kv_chunk)
     scale = hd ** -0.5 if scale is None else scale
@@ -96,7 +113,7 @@ def chunked_sdpa(q: jax.Array, k: jax.Array, v: jax.Array, q_pos: jax.Array,
     qc = q.reshape(B, nq, q_chunk, H, hd).swapaxes(0, 1)        # (nq,B,qc,H,hd)
     qp = q_pos.reshape(nq, q_chunk)
     kc = k.reshape(B, nk, kv_chunk, H, hd).swapaxes(0, 1)       # (nk,B,kc,H,hd)
-    vc = v.reshape(B, nk, kv_chunk, H, hd).swapaxes(0, 1)
+    vc = v.reshape(B, nk, kv_chunk, H, dv).swapaxes(0, 1)
     kp = k_pos.reshape(nk, kv_chunk)
     if k_valid is None:
         kval = jnp.ones((nk, kv_chunk), bool)
@@ -125,13 +142,90 @@ def chunked_sdpa(q: jax.Array, k: jax.Array, v: jax.Array, q_pos: jax.Array,
 
         m0 = jnp.full((B, H, q_chunk), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, H, q_chunk), jnp.float32)
-        a0 = jnp.zeros((B, q_chunk, H, hd), jnp.float32)
+        a0 = jnp.zeros((B, q_chunk, H, dv), jnp.float32)
         (m, l, acc), _ = jax.lax.scan(kv_step, (m0, l0, a0), (kc, vc, kp, kval))
         out = acc / jnp.maximum(l, 1e-30)[..., None].swapaxes(1, 2)
         return None, out.astype(q.dtype)
 
     _, out = jax.lax.scan(q_step, None, (qc, qp))
-    return out.swapaxes(0, 1).reshape(B, Sq, H, hd)
+    return out.swapaxes(0, 1).reshape(B, Sq, H, dv)
+
+
+# ======================================================= fused causal kernel
+def _fused_blocks(S: int) -> Optional[splash.BlockSizes]:
+    """The fused kernel's blocks at sequence S; None where S is no
+    multiple of a block.  Queries go in blocks of FUSED_BLOCK (all of S
+    when shorter), keys and values in twice that where S allows, and the
+    backward runs as one kernel (dq beside dk/dv), which re-reads fewer
+    blocks than separate dq and dk/dv kernels.  On a TPU v5e these were
+    the fastest of the blocks tried at both training cells' shapes."""
+    b = min(FUSED_BLOCK, S)
+    if S % b or b % FUSED_MIN_BLOCK:
+        return None
+    kv = 2 * b if S % (2 * b) == 0 else b
+    return splash.BlockSizes(
+        block_q=b, block_kv=kv, block_kv_compute=b,
+        block_q_dkv=b, block_kv_dkv=kv, block_kv_dkv_compute=b,
+        use_fused_bwd_kernel=True)
+
+
+def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
+                     scale: float, interpret: bool = False) -> jax.Array:
+    """Causal softmax attention through the fused kernel, forward and
+    backward.  q: (B,S,H,dqk), k: (B,S,KV,dqk), v: (B,S,KV,dv) with H a
+    multiple of KV -> (B,S,H,dv).
+
+    Each KV head serves its group of H/KV query heads in the kernel's
+    MQA form, so K/V are never repeated.  The scale is folded into q in
+    f32 before the cast back to q's dtype; the softmax statistics and
+    accumulators are f32 inside the kernel."""
+    B, S, H, dqk = q.shape
+    kv, dv = k.shape[2], v.shape[-1]
+    g = H // kv
+    kernel = splash.make_splash_mqa_single_device(
+        splash.MultiHeadMask([splash.CausalMask((S, S))] * g),
+        block_sizes=_fused_blocks(S), interpret=interpret)
+    qs = (q.astype(jnp.float32) * scale).astype(q.dtype)
+    qg = qs.reshape(B, S, kv, g, dqk).transpose(0, 2, 3, 1, 4)  # (B,kv,g,S,d)
+    out = jax.vmap(jax.vmap(kernel))(qg, k.transpose(0, 2, 1, 3),
+                                     v.transpose(0, 2, 1, 3))
+    return out.transpose(0, 3, 1, 2, 4).reshape(B, S, H, dv)
+
+
+def _on_one_device(x: jax.Array) -> bool:
+    """No mesh axis that could split x (its batch or heads) spans more than
+    one device: neither x's mesh nor the context's has such an axis, a
+    shard_map's manual axes aside."""
+    for mesh in (jax.typeof(x).sharding.mesh, jax.sharding.get_abstract_mesh()):
+        manual = set(mesh.manual_axes)
+        if any(n > 1 for a, n in mesh.shape.items() if a not in manual):
+            return False
+    return True
+
+
+def fused_attention_applies(q: jax.Array, k: jax.Array, *, causal: bool,
+                            window: int = 0,
+                            k_valid: Optional[jax.Array] = None,
+                            cross: bool = False) -> bool:
+    """Whether full-sequence attention may take the fused kernel: causal
+    self-attention over every key (no window, no padding mask), S a
+    multiple of the kernel's block, batch and heads on one device.  The
+    platform is decided where the call is lowered (``_attend``)."""
+    S = q.shape[1]
+    return (causal and not window and k_valid is None and not cross
+            and k.shape[1] == S and _fused_blocks(S) is not None
+            and _on_one_device(q))
+
+
+def _attend(fused: bool, fallback: Callable, q, k, v, *, scale: float):
+    """``causal_attention`` where the call is lowered for a TPU and
+    ``fused`` holds; ``fallback(q, k, v)`` otherwise (and only that branch
+    is lowered)."""
+    if not fused:
+        return fallback(q, k, v)
+    return jax.lax.platform_dependent(
+        q, k, v, tpu=functools.partial(causal_attention, scale=scale),
+        default=fallback)
 
 
 def gqa_forward(cfg, p: Params, x: jax.Array, positions: jax.Array, *,
@@ -157,14 +251,20 @@ def gqa_forward(cfg, p: Params, x: jax.Array, positions: jax.Array, *,
     else:
         k, v = kv_override
     cache = {"k": k, "v": v}
-    kf, vf = _repeat_kv(k, h), _repeat_kv(v, h)
     k_pos = positions if kv_override is None else jnp.arange(k.shape[1])
-    if max(S, k.shape[1]) > CHUNK_THRESHOLD:
-        out = chunked_sdpa(q, kf, vf, positions, k_pos, causal=causal,
-                           window=window, k_valid=k_valid)
-    else:
-        out = sdpa(q, kf, vf, positions, k_pos, causal=causal, window=window,
-                   k_valid=k_valid)
+
+    def unfused(q, k, v):
+        kf, vf = _repeat_kv(k, h), _repeat_kv(v, h)
+        if max(S, k.shape[1]) > CHUNK_THRESHOLD:
+            return chunked_sdpa(q, kf, vf, positions, k_pos, causal=causal,
+                                window=window, k_valid=k_valid)
+        return sdpa(q, kf, vf, positions, k_pos, causal=causal, window=window,
+                    k_valid=k_valid)
+
+    fused = fused_attention_applies(q, k, causal=causal, window=window,
+                                    k_valid=k_valid,
+                                    cross=kv_override is not None)
+    out = _attend(fused, unfused, q, k, v, scale=cfg.head_dim_ ** -0.5)
     return jnp.einsum("bshk,hkd->bsd", out, p["wo"]), cache
 
 
@@ -306,7 +406,6 @@ def mla_forward(cfg, p: Params, x: jax.Array, positions: jax.Array,
                 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Train/prefill MLA with naive (expanded) K/V; latent cache returned."""
     B, S, _ = x.shape
-    nope, vh = cfg.qk_nope_dim, cfg.v_head_dim
     q_nope, q_rope = _mla_q(cfg, p, x, positions)
     ckv, k_rope = _mla_latent(cfg, p, x, positions)
     k_nope = jnp.einsum("bsr,rhk->bshk", ckv, p["w_uk"])
@@ -315,14 +414,15 @@ def mla_forward(cfg, p: Params, x: jax.Array, positions: jax.Array,
     k_rope_b = jnp.broadcast_to(k_rope[:, :, None, :], (B, S, h, cfg.qk_rope_dim))
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
     k = jnp.concatenate([k_nope, k_rope_b], axis=-1)
-    # pad v to qk dim for the shared chunked kernel, then slice back
-    if S > CHUNK_THRESHOLD:
-        vp = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, q.shape[-1] - vh)))
-        out = chunked_sdpa(q, k, vp, positions, positions, causal=True,
-                           k_valid=k_valid, scale=mla_softmax_scale(cfg))[..., :vh]
-    else:
-        out = sdpa(q, k, v, positions, positions, causal=True, k_valid=k_valid,
-                   scale=mla_softmax_scale(cfg))
+    scale = mla_softmax_scale(cfg)
+
+    def unfused(q, k, v):
+        attend = chunked_sdpa if S > CHUNK_THRESHOLD else sdpa
+        return attend(q, k, v, positions, positions, causal=True,
+                      k_valid=k_valid, scale=scale)
+
+    fused = fused_attention_applies(q, k, causal=True, k_valid=k_valid)
+    out = _attend(fused, unfused, q, k, v, scale=scale)
     cache = {"ckv": ckv, "k_rope": k_rope}
     return jnp.einsum("bshk,hkd->bsd", out, p["wo"]), cache
 

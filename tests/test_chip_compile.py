@@ -1,5 +1,7 @@
 """Compile every Pallas kernel for a described TPU v5e chip, at the widths
-the main path runs them, with the blocks ``resolve_blocks`` picks.
+the main path runs them, with the blocks ``resolve_blocks`` picks, and the
+training cells' train steps, one layer deep, whose attention runs in the
+fused causal kernel.
 
 Nothing runs: the TPU compiler lowers each kernel for a chip that is
 described, not attached, and refuses what the chip would refuse (VMEM
@@ -7,6 +9,7 @@ overflow, tile misalignment).  The topology is described inside a
 module-scoped fixture only: the TPU library may be loaded by one process
 at a time, and every pytest worker imports this file.
 """
+import dataclasses
 import functools
 import os
 import re
@@ -16,6 +19,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro import configs
 from repro.kernels import autotune
 from repro.kernels.flash_attention import flash_attention as fa_kernel
 from repro.kernels.flash_attention import ops as fa_ops
@@ -23,6 +27,9 @@ from repro.kernels.kmeans import kmeans as km_kernel
 from repro.kernels.kmeans import ops as km_ops
 from repro.kernels.mamba_scan import mamba_scan as ms_kernel
 from repro.kernels.mamba_scan import ops as ms_ops
+from repro.models import transformer
+from repro.models.layers import attention
+from repro.train.step import make_train_state, make_train_step
 
 
 @pytest.fixture(scope="module")
@@ -126,3 +133,57 @@ def test_kmeans_ops_wrapper_picks_compiled_kernel(one_chip):
                         _spec((10_240, 3), jnp.float32, one_chip),
                         _spec((5_120, 3), jnp.float32, one_chip))
     _assert_kmeans_results(compiled, 10_240)
+
+
+# the training cells' attention: InternLM2 (16 query heads over 8 KV heads,
+# hd 128) at 2 x 2048, and DeepSeek-V2-Lite's MLA (16 heads, q.k 192, V 128)
+# at 4 x 4096
+ATTN_SHAPES = {"internlm2": (2, 2048, 16, 8, 128, 128),
+               "mla": (4, 4096, 16, 16, 192, 128)}
+
+
+@pytest.mark.parametrize("form", sorted(ATTN_SHAPES))
+def test_fused_attention_compiles_forward_and_backward(one_chip, form):
+    """The fused causal kernel's custom calls: the forward, and one
+    backward kernel that gives dq, dk and dv."""
+    B, S, H, KV, dqk, dv = ATTN_SHAPES[form]
+
+    def loss(q, k, v):
+        out = attention.causal_attention(q, k, v, scale=dqk ** -0.5)
+        return jnp.sum(out.astype(jnp.float32))
+
+    compiled = _compile(jax.grad(loss, (0, 1, 2)),
+                        _spec((B, S, H, dqk), jnp.bfloat16, one_chip),
+                        _spec((B, S, KV, dqk), jnp.bfloat16, one_chip),
+                        _spec((B, S, KV, dv), jnp.bfloat16, one_chip))
+    calls = set(re.findall(
+        r"%splash_mqa_(fwd|dq|dkv)_\w+\.\d+ = .*custom-call\(",
+        compiled.as_text()))
+    assert calls == {"fwd", "dkv"}
+
+
+def _one_layer_train_step(one_chip, arch, batch, seq) -> str:
+    """HLO of one train step of ``arch`` cut to one layer, compiled for
+    the chip."""
+    cfg = dataclasses.replace(configs.get(arch), n_layers=1)
+    state = jax.eval_shape(lambda: make_train_state(
+        cfg, transformer.init_params(cfg, jax.random.key(0))))
+    spec = lambda x: _spec(x.shape, x.dtype, one_chip)
+    tokens = _spec((batch, seq), jnp.int32, one_chip)
+    b = {"tokens": tokens, "labels": tokens,
+         "mask": _spec((batch, seq), jnp.float32, one_chip)}
+    return _compile(make_train_step(cfg), jax.tree.map(spec, state),
+                    b).as_text()
+
+
+@pytest.mark.parametrize("arch,batch,seq,scores", [
+    ("internlm2-1.8b", 2, 2048, r"f32\[2,16,2048,2048\]"),
+    ("deepseek-v2-lite", 4, 4096, r"\[4,16,1024,1024\]")],
+    ids=["internlm2", "deepseek-v2-lite"])
+def test_train_step_holds_no_score_array(one_chip, arch, batch, seq, scores):
+    """The train steps of both training cells, one layer deep: attention
+    runs in the fused kernel, and neither the whole score matrix nor a
+    chunk's block of logits is an array of the program."""
+    text = _one_layer_train_step(one_chip, arch, batch, seq)
+    assert "%splash_mqa_dkv" in text
+    assert not re.search(scores, text)

@@ -1,55 +1,21 @@
-"""Autotuner + roofline-placement benchmark (PR 7's two halves).
+"""Roofline-aware placement benchmark.
 
-Kernel arm — for each Pallas kernel, time the shipped default block
-config against the autotuned winner from a fresh registry, then re-run
-the autotuner to show the cached registry short-circuits (0 trials).
-The CI floor: tuned must be >= 1.1x default on at least one kernel.
+Two HPC pilots advertise contrasting rooflines ("bigflops": high peak
+FLOP/s, thin HBM; "bigmem": the reverse).  A compute-bound and a
+memory-bound stage consume the SAME dataset (equal bytes), so the
+byte-only placer co-locates them wherever the data landed; the
+roofline-aware placer splits them by modeled est_runtime, and the modeled
+makespan drops.
 
-Placement arm — two HPC pilots advertise contrasting rooflines
-("bigflops": high peak FLOP/s, thin HBM; "bigmem": the reverse).  A
-compute-bound and a memory-bound stage consume the SAME dataset (equal
-bytes), so the byte-only placer co-locates them wherever the data
-landed; the roofline-aware placer splits them by modeled est_runtime,
-and the modeled makespan drops.
-
-    PYTHONPATH=src python benchmarks/bench_autotune.py [--smoke] [--json PATH]
+    PYTHONPATH=src python benchmarks/bench_roofline_placement.py [--smoke] [--json PATH]
 """
 from __future__ import annotations
 
 import argparse
 import json
-import os
-import tempfile
 from typing import Any, Dict, List
 
 import numpy as np
-
-
-# ------------------------------------------------------------- kernel arm
-def kernel_arm(smoke: bool = False) -> List[Dict[str, Any]]:
-    from repro.kernels import autotune as at
-    reps = 2 if smoke else 4
-    max_cands = 8 if smoke else None
-    rows = []
-    with tempfile.TemporaryDirectory() as td:
-        reg = at.Registry(os.path.join(td, "autotune.json"))
-        for kern in at.KERNELS:
-            first = at.autotune(kern, reps=reps, registry=reg,
-                                max_candidates=max_cands)
-            again = at.autotune(kern, reps=reps, registry=reg,
-                                max_candidates=max_cands)
-            rows.append({
-                "kernel": kern,
-                "default_config": first["default_config"],
-                "tuned_config": first["config"],
-                "default_us": first["default_s"] * 1e6,
-                "tuned_us": first["best_s"] * 1e6,
-                "speedup_vs_default": first["speedup_vs_default"],
-                "trials_first": first["trials"],
-                "trials_second": again["trials"],
-                "registry_reuse": again["cached"] and again["trials"] == 0,
-            })
-    return rows
 
 
 # ---------------------------------------------------------- placement arm
@@ -135,17 +101,11 @@ def placement_arm() -> List[Dict[str, Any]]:
 
 # ----------------------------------------------------------------- driver
 def run() -> List[Dict]:
-    """Driver-format rows (benchmarks/run.py section 'autotune')."""
+    """Rows for benchmarks/run.py, section 'roofline_placement'."""
     rows = []
-    for r in kernel_arm(smoke=True):
-        rows.append({"name": f"autotune/{r['kernel']}",
-                     "us_per_call": r["tuned_us"],
-                     "derived": (f"default_us={r['default_us']:.0f} "
-                                 f"speedup={r['speedup_vs_default']:.2f}x "
-                                 f"reuse={r['registry_reuse']}")})
     for r in placement_arm():
         tag = "roofline" if r["roofline_placement"] else "bytes_only"
-        rows.append({"name": f"autotune/placement/{tag}",
+        rows.append({"name": f"roofline_placement/{tag}",
                      "us_per_call": r["modeled_makespan_s"] * 1e6,
                      "derived": (f"compute_on={r['compute_on']} "
                                  f"memory_on={r['memory_on']} "
@@ -156,50 +116,32 @@ def run() -> List[Dict]:
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--smoke", action="store_true",
-                    help="CI mode: fewer reps/candidates, writes --json, "
-                         "enforces the 1.1x floor + placement split")
+                    help="CI mode: writes --json, enforces the placement "
+                         "split")
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="write results as JSON (default "
-                         "BENCH_autotune.json with --smoke)")
+                         "BENCH_roofline_placement.json with --smoke)")
     args = ap.parse_args()
 
-    kernels = kernel_arm(smoke=args.smoke)
     placement = placement_arm()
-    out = {"kernels": kernels, "placement": placement}
-    json_path = args.json or ("BENCH_autotune.json" if args.smoke else None)
+    out = {"placement": placement}
+    json_path = args.json or ("BENCH_roofline_placement.json" if args.smoke
+                              else None)
     if json_path:
         with open(json_path, "w") as f:
             json.dump(out, f, indent=2)
         print(f"wrote {json_path}")
 
-    print(f"{'kernel':<16} {'default':>18} {'tuned':>18} "
-          f"{'speedup':>8} {'reuse':>6}")
-    print("-" * 70)
-    for r in kernels:
-        print(f"{r['kernel']:<16} {str(r['default_config']):>18} "
-              f"{str(r['tuned_config']):>18} "
-              f"{r['speedup_vs_default']:>7.2f}x {str(r['registry_reuse']):>6}")
-    print()
     for r in placement:
         tag = "roofline" if r["roofline_placement"] else "bytes-only"
         print(f"placement[{tag:>10}]: compute->{r['compute_on']:<9} "
               f"memory->{r['memory_on']:<9} split={r['split']} "
               f"modeled_makespan={r['modeled_makespan_s']:.1f}s")
 
-    best = max(r["speedup_vs_default"] for r in kernels)
-    reuse = all(r["registry_reuse"] for r in kernels)
     off, on = placement
-    print(f"\nbest tuned speedup: {best:.2f}x; registry reuse on second "
-          f"run: {reuse}")
     print(f"roofline split makespan {on['modeled_makespan_s']:.1f}s vs "
           f"byte-only {off['modeled_makespan_s']:.1f}s")
     if args.smoke:
-        if best < 1.1:
-            raise SystemExit(f"FLOOR MISS: best tuned speedup {best:.2f}x "
-                             "< 1.1x on every kernel")
-        if not reuse:
-            raise SystemExit("registry reuse failed: second autotune run "
-                             "re-timed trials")
         if not on["split"] or off["split"]:
             raise SystemExit(
                 "placement check failed: expected byte-only co-location "

@@ -10,8 +10,7 @@ Prints ``name,us_per_call,derived`` CSV.
   dispatch  — Raptor overlay vs per-CU scheduler dispatch throughput
   staging   — async prefetch + replica cache vs synchronous staging
   serve     — disaggregated prefill/decode serving vs static engine
-  kernels   — Pallas kernel micro-benchmarks vs jnp reference
-  autotune  — tuned vs default block configs + roofline placement split
+  roofline_placement — roofline-aware vs byte-only placement split
   roofline  — per-(arch x shape x mesh) roofline terms from the dry-run
 """
 from __future__ import annotations
@@ -24,14 +23,14 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     choices=[None, "fig5", "fig6", "fig8", "elastic",
-                             "fairshare", "dispatch", "staging", "serve", "kernels",
-                             "autotune", "roofline", "chaos"])
+                             "fairshare", "dispatch", "staging", "serve",
+                             "roofline_placement", "roofline", "chaos"])
     args = ap.parse_args()
 
     from repro.launch.cache import enable_compile_cache
     enable_compile_cache()
-    from benchmarks import (bench_autotune, bench_chaos, bench_dispatch,
-                            bench_elastic, bench_fairshare, bench_kernels,
+    from benchmarks import (bench_chaos, bench_dispatch, bench_elastic,
+                            bench_fairshare, bench_roofline_placement,
                             bench_session_placement,
                             bench_serve_scale, bench_staging,
                             fig5_overheads, fig6_kmeans,
@@ -45,8 +44,7 @@ def main() -> None:
         "dispatch": bench_dispatch.run,
         "staging": bench_staging.run,
         "serve": bench_serve_scale.run,
-        "kernels": bench_kernels.run,
-        "autotune": bench_autotune.run,
+        "roofline_placement": bench_roofline_placement.run,
         "roofline": roofline_table.run,
         "chaos": bench_chaos.run,
     }

@@ -381,9 +381,7 @@ def one_chip(full) -> None:
         f"{cut.n_layers} layers (16 GiB with AdamW state); batch "
         f"{TRAIN_BATCH} x {TRAIN_SEQ} tokens, {TRAIN_STEPS} steps")
     log(f"kernels: kmeans blocks (bn, bk) = "
-        f"{km_ops.resolve_blocks(n, k, km.PAPER_DIM, 'float32', None, None)}"
-        f" at {n} x {km.PAPER_DIM}, k={k}; flash_attention and mamba_scan "
-        f"are not on this path")
+        f"{km_ops.resolve_blocks(n, k)} at {n} x {km.PAPER_DIM}, k={k}")
     # two pilots over the one chip: each lease slot aliases it
     out = run_dag(cut, ResourceManager(devices=jax.devices()[:1] * 2))
     sim, an = out["results"]["simulate"], out["results"]["analyze"]
@@ -452,7 +450,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         return 2
 
     from repro import configs
-    from repro.kernels import autotune
     from repro.launch.cache import enable_compile_cache
     from repro.roofline.terms import chip_spec
     hw = chip_spec(dev)        # an unknown TPU kind stops the run here
@@ -460,9 +457,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         f"{hw.peak_flops:.3g} FLOP/s {hw.hbm_bw:.3g} B/s, "
         f"{hw.hbm_bytes:.3g} B HBM")
     log(f"compile cache: {enable_compile_cache()}")
-    log(f"autotune registry: {autotune.default_registry().path} "
-        f"({len(autotune.default_registry())} entries; DEFAULTS "
-        f"{autotune.DEFAULTS})")
 
     full = configs.get("llama3.2-1b")
     t0 = time.monotonic()

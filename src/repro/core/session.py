@@ -171,8 +171,8 @@ class Session:
         # instead of the synchronous move in _ensure_inputs_on
         self.prefetch = prefetch
         # roofline_placement=False drops the est_runtime term (byte-only
-        # scoring — the on/off arm of bench_autotune); stages carrying
-        # no StageCost are byte-only either way.  calibrate_estimates
+        # scoring — the on/off arm of bench_roofline_placement); stages
+        # carrying no StageCost are byte-only either way.  calibrate_estimates
         # additionally multiplies each pilot's est_runtime by that
         # pilot's observed EMA actual/estimate ratio — off by default:
         # the error is always EXPORTED (heartbeats + placements), it is

@@ -1,12 +1,14 @@
-"""Pallas TPU kernels for the framework's compute hot-spots.
+"""Pallas TPU kernel for the framework's compute hot-spot: K-Means
+assignment.
 
-Each kernel ships as a triple:
-  <name>/<name>.py — pl.pallas_call + BlockSpec VMEM tiling (TPU target)
-  <name>/ops.py    — jit'd public wrapper (padding, block choice)
-  <name>/ref.py    — pure-jnp oracle used by the allclose sweeps
+It ships as a triple:
+  kmeans/kmeans.py — pl.pallas_call + BlockSpec VMEM tiling (TPU target)
+  kmeans/ops.py    — jit'd public wrapper (padding, block choice)
+  kmeans/ref.py    — pure-jnp oracle used by the allclose sweeps
 
-Kernels run compiled on the accelerator and under the Pallas interpreter
-only where the call is lowered for the CPU (:func:`pallas_on_platform`).
+The kernel runs compiled on the accelerator and under the Pallas
+interpreter only where the call is lowered for the CPU
+(:func:`pallas_on_platform`).
 """
 from __future__ import annotations
 

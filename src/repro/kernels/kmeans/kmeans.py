@@ -32,6 +32,9 @@ CHUNK_ROWS = 32     # rows of points whose running pair stays in vregs
 MAX_BK = 128        # a block's centroids are unrolled whole: cap the code
 SMEM_TILE = 1024    # words: the tile of a 1-D operand
 TILE_POINTS = 8 * LANES
+# points and centroids a block of the lane-dense assignment kernel
+BLOCK_POINTS = 65536
+BLOCK_CENTROIDS = 64
 
 
 def _kernel(c_ref, p_ref, idx_ref, min_ref, *, d: int, bk: int,

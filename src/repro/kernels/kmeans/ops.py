@@ -1,4 +1,4 @@
-"""Public wrapper for the K-Means assignment kernel (autotuned blocks)."""
+"""Public wrapper for the K-Means assignment kernel."""
 from __future__ import annotations
 
 import functools
@@ -7,10 +7,10 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import autotune, pallas_on_platform
+from repro.kernels import pallas_on_platform
 from . import kmeans as kernel
 
-_BLOCK_POINTS = kernel.CHUNK_ROWS * kernel.LANES   # bn is a multiple of this
+_CHUNK_POINTS = kernel.CHUNK_ROWS * kernel.LANES   # bn is a multiple of this
 
 
 def _round_up(x: int, m: int) -> int:
@@ -39,18 +39,15 @@ def _assign(points, centroids, bn: int, bk: int):
     return (idx, mind) if n128 == n else (idx[:n], mind[:n])
 
 
-def resolve_blocks(n: int, k: int, d: int, dtype,
-                   bn: Optional[int], bk: Optional[int]):
-    """Block sizes for assignment: explicit args win, else the autotune
-    registry, else ``autotune.DEFAULTS``. ``bn`` (points a block) is
+def resolve_blocks(n: int, k: int, bn: Optional[int] = None,
+                   bk: Optional[int] = None) -> Tuple[int, int]:
+    """Block sizes for assignment: explicit args win, else the kernel's
+    ``BLOCK_POINTS`` and ``BLOCK_CENTROIDS``. ``bn`` (points a block) is
     rounded to a multiple of the kernel's chunk and capped to the padded n;
     ``bk`` (centroids a block) is capped to k and to ``kernel.MAX_BK``."""
-    if bn is None or bk is None:
-        tuned = autotune.lookup("kmeans", {"n": n, "k": k, "d": d}, dtype) \
-            or autotune.DEFAULTS["kmeans"]
-        bn = bn if bn is not None else tuned["bn"]
-        bk = bk if bk is not None else tuned["bk"]
-    bn = min(_round_up(bn, _BLOCK_POINTS), _round_up(n, _BLOCK_POINTS))
+    bn = kernel.BLOCK_POINTS if bn is None else bn
+    bk = kernel.BLOCK_CENTROIDS if bk is None else bk
+    bn = min(_round_up(bn, _CHUNK_POINTS), _round_up(n, _CHUNK_POINTS))
     return bn, min(bk, k, kernel.MAX_BK)
 
 
@@ -59,7 +56,5 @@ def assign(points: jax.Array, centroids: jax.Array, *,
            bk: Optional[int] = None) -> Tuple[jax.Array, jax.Array]:
     """Nearest centroid of each point and the squared distance to it:
     ``(idx (n,) int32, dist (n,) f32)``, as ``ref.assign`` gives them."""
-    n, d = points.shape
-    k = centroids.shape[0]
-    bn, bk = resolve_blocks(n, k, d, points.dtype, bn, bk)
+    bn, bk = resolve_blocks(points.shape[0], centroids.shape[0], bn, bk)
     return _assign(points, centroids, bn, bk)

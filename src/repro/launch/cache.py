@@ -1,17 +1,13 @@
 """Where the program keeps what it caches between runs.
 
-Both caches sit at fixed paths inside the checkout unless the caller
-names another place, so a second run from the same checkout finds what
-the first one wrote.  A cache path is never made from a temp name, a pid
-or the time: the path is part of the compile cache's key.
+JAX's persistent compilation cache sits at a fixed path inside the
+checkout unless the caller names another place, so a second run from the
+same checkout finds what the first one wrote.  The path is never made
+from a temp name, a pid or the time: it is part of the cache's key.
 
-* JAX's persistent compilation cache: ``JAX_COMPILATION_CACHE_DIR`` when
-  it is set (JAX reads the variable itself and this module sets no
-  other), else ``<checkout>/.jax_cache``.
-* The kernel autotune registry: ``REPRO_AUTOTUNE_REGISTRY`` when it is
-  set, else ``<checkout>/.cache/autotune.json``.
-
-Both in-checkout paths are listed in ``.gitignore``.
+``JAX_COMPILATION_CACHE_DIR`` wins when it is set (JAX reads the variable
+itself and this module sets no other), else ``<checkout>/.jax_cache``,
+which is listed in ``.gitignore``.
 """
 from __future__ import annotations
 
@@ -37,8 +33,3 @@ def enable_compile_cache() -> str:
         jax.config.update("jax_compilation_cache_dir", path)
     return path
 
-
-def autotune_registry_path() -> str:
-    """The kernel autotune registry the ops wrappers read."""
-    return (os.environ.get("REPRO_AUTOTUNE_REGISTRY")
-            or os.path.join(CHECKOUT, ".cache", "autotune.json"))

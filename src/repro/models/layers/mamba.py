@@ -7,8 +7,6 @@ channels are independent, so the recurrence needs **zero** collectives —
 and (b) run a chunked scan: `lax.scan` over sequence chunks carrying the
 (B, d_inner, d_state) state, with an associative scan *inside* each chunk.
 This bounds live memory to one chunk while keeping VPU-parallel work wide.
-The per-chunk inner scan is also implemented as a Pallas kernel
-(kernels/mamba_scan) for the TPU hot-spot path.
 """
 from __future__ import annotations
 
@@ -70,6 +68,29 @@ def _chunk_scan(a: jax.Array, b: jax.Array, h0: jax.Array):
     return h, h[:, -1]
 
 
+def selective_scan(a: jax.Array, b: jax.Array, C: jax.Array, h0: jax.Array,
+                   chunk: int) -> Tuple[jax.Array, jax.Array]:
+    """Chunked selective scan: ``lax.scan`` over chunks of ``chunk`` steps
+    carrying the state, an associative scan inside each chunk.
+    a, b: (B,S,di,st); C: (B,S,st); h0: (B,di,st) ->
+    (y (B,S,di) f32, h_last (B,di,st)), where
+    ``h_t = a_t * h_{t-1} + b_t`` and ``y_t = sum_st h_t * C_t``."""
+    B, S, di, st = a.shape
+    nc = S // chunk
+    assert S % chunk == 0, (S, chunk)
+
+    a_c = a.reshape(B, nc, chunk, di, st).swapaxes(0, 1)
+    b_c = b.reshape(B, nc, chunk, di, st).swapaxes(0, 1)
+
+    def step(h, ab):
+        h_all, h_last = _chunk_scan(ab[0], ab[1], h)
+        return h_last, h_all
+
+    h_last, h_all = jax.lax.scan(step, h0, (a_c, b_c))
+    h_all = h_all.swapaxes(0, 1).reshape(B, S, di, st)
+    return jnp.einsum("bsin,bsn->bsi", h_all, C.astype(jnp.float32)), h_last
+
+
 def _causal_conv(p: Params, x1: jax.Array) -> jax.Array:
     """Depthwise causal conv1d as a sum of shifted copies (kernel is tiny)."""
     dc = p["conv_w"].shape[0]
@@ -91,22 +112,8 @@ def mamba_forward(cfg, p: Params, x: jax.Array,
     x1 = jax.nn.silu(_causal_conv(p, x1).astype(jnp.float32)).astype(x.dtype)
 
     a, b, Cc = _ssm_inputs(cfg, p, x1)
-    chunk = min(SCAN_CHUNK, S)
-    nc = S // chunk
-    assert S % chunk == 0, (S, chunk)
-
-    a_c = a.reshape(B, nc, chunk, di, st).swapaxes(0, 1)
-    b_c = b.reshape(B, nc, chunk, di, st).swapaxes(0, 1)
-
-    def step(h, ab):
-        h_all, h_last = _chunk_scan(ab[0], ab[1], h)
-        return h_last, h_all
-
     h0 = jnp.zeros((B, di, st), jnp.float32)
-    h_last, h_all = jax.lax.scan(step, h0, (a_c, b_c))
-    h_all = h_all.swapaxes(0, 1).reshape(B, S, di, st)
-
-    y = jnp.einsum("bsin,bsn->bsi", h_all, Cc.astype(jnp.float32))
+    y, h_last = selective_scan(a, b, Cc, h0, min(SCAN_CHUNK, S))
     y = y + p["D"] * x1.astype(jnp.float32)
     y = (y * jax.nn.silu(z.astype(jnp.float32))).astype(x.dtype)
     out = jnp.einsum("bsi,id->bsd", y, p["out_proj"])

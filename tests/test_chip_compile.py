@@ -20,13 +20,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro import configs
-from repro.kernels import autotune
-from repro.kernels.flash_attention import flash_attention as fa_kernel
-from repro.kernels.flash_attention import ops as fa_ops
 from repro.kernels.kmeans import kmeans as km_kernel
 from repro.kernels.kmeans import ops as km_ops
-from repro.kernels.mamba_scan import mamba_scan as ms_kernel
-from repro.kernels.mamba_scan import ops as ms_ops
 from repro.models import transformer
 from repro.models.layers import attention
 from repro.train.step import make_train_state, make_train_step
@@ -51,15 +46,6 @@ def one_chip():
     jax.config.update("jax_enable_compilation_cache", prev)
 
 
-@pytest.fixture(autouse=True)
-def shipped_blocks(monkeypatch, tmp_path):
-    """Resolve blocks from the shipped DEFAULTS, never a local registry."""
-    monkeypatch.setenv("REPRO_AUTOTUNE_REGISTRY", str(tmp_path / "none.json"))
-    autotune.default_registry(reload=True)
-    yield
-    autotune.default_registry(reload=True)
-
-
 def _spec(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
@@ -81,7 +67,7 @@ def _assert_kmeans_results(compiled, n):
 def test_kmeans_assign_compiles(one_chip, n, k):
     """The paper's 1M x 3 scenario, with 50 and 5,000 centroids."""
     d = 3
-    bn, bk = km_ops.resolve_blocks(n, k, d, jnp.float32, None, None)
+    bn, bk = km_ops.resolve_blocks(n, k)
     rows = -(-n // km_kernel.LANES)
     centroid_words = -(-k // bk) * km_kernel.centroid_stride(d, bk)
     fn = functools.partial(km_kernel.assign_pallas, br=bn // km_kernel.LANES,
@@ -103,29 +89,6 @@ def test_kmeans_block_assign_compiles(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes <= 2 * n * d * 4
 
 
-@pytest.mark.parametrize("hd", [64, 128])
-def test_flash_attention_compiles(one_chip, hd):
-    """llama3.2-1b widths (hd=64, 32 heads, S=2048) in bf16."""
-    BH, S = 32, 2048
-    bq, bk = fa_ops.resolve_blocks(S, S, hd, jnp.bfloat16, None, None)
-    fn = functools.partial(fa_kernel.flash_attention_pallas, bq=bq, bk=bk,
-                           interpret=False)
-    spec = _spec((BH, S, hd), jnp.bfloat16, one_chip)
-    _compile(fn, spec, spec, spec)
-
-
-def test_mamba_scan_compiles(one_chip):
-    """falcon-mamba widths: d_inner 8192, state 16."""
-    B, S, di, st = 1, 256, 8192, 16
-    bdi, bs = ms_ops.resolve_blocks(S, di, st, jnp.float32, None, None)
-    fn = functools.partial(ms_kernel.mamba_scan_pallas, bdi=bdi, bs=bs,
-                           interpret=False)
-    _compile(fn, _spec((B, S, di, st), jnp.float32, one_chip),
-             _spec((B, S, di, st), jnp.float32, one_chip),
-             _spec((B, S, st), jnp.float32, one_chip),
-             _spec((B, di, st), jnp.float32, one_chip))
-
-
 def test_kmeans_ops_wrapper_picks_compiled_kernel(one_chip):
     """The public wrapper, lowered for the chip, takes the compiled branch
     (interpret mode is chosen per lowering platform, not by a global)."""
@@ -137,9 +100,11 @@ def test_kmeans_ops_wrapper_picks_compiled_kernel(one_chip):
 
 # the training cells' attention: InternLM2 (16 query heads over 8 KV heads,
 # hd 128) at 2 x 2048, and DeepSeek-V2-Lite's MLA (16 heads, q.k 192, V 128)
-# at 4 x 4096
+# at 4 x 4096; and llama3.2-1b's (32 query heads over 8 KV heads, hd 64,
+# a head half a lane row wide) at 1 x 2048
 ATTN_SHAPES = {"internlm2": (2, 2048, 16, 8, 128, 128),
-               "mla": (4, 4096, 16, 16, 192, 128)}
+               "mla": (4, 4096, 16, 16, 192, 128),
+               "llama3.2-1b": (1, 2048, 32, 8, 64, 64)}
 
 
 @pytest.mark.parametrize("form", sorted(ATTN_SHAPES))

@@ -116,11 +116,6 @@ def test_enable_compile_cache_sets_in_checkout_dir(monkeypatch):
         jax.config.update("jax_compilation_cache_dir", prev)
 
 
-def test_autotune_registry_defaults_into_checkout(monkeypatch):
-    monkeypatch.delenv("REPRO_AUTOTUNE_REGISTRY", raising=False)
-    assert cache.autotune_registry_path().startswith(ROOT + os.sep)
-
-
 # ----------------------------------------------------------- device table
 def _device(platform, kind):
     return types.SimpleNamespace(platform=platform, device_kind=kind)

@@ -1,5 +1,6 @@
-"""Per-kernel shape/dtype sweeps: pallas (interpreted on the CPU) vs
-pure-jnp oracle."""
+"""K-Means assignment kernel: shape/dtype sweeps of the Pallas kernel
+(interpreted on the CPU) against the pure-jnp oracle, and the blocks the
+wrapper picks."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -7,9 +8,7 @@ import pytest
 pytest.importorskip("hypothesis")  # suite degrades to skips without it
 from hypothesis import given, settings, strategies as st
 
-from repro.kernels.flash_attention import ops as fa_ops, ref as fa_ref
 from repro.kernels.kmeans import ops as km_ops, ref as km_ref
-from repro.kernels.mamba_scan import ops as ms_ops, ref as ms_ref
 
 
 # ------------------------------------------------------------------ kmeans
@@ -72,65 +71,20 @@ def test_kmeans_assign_property(n, k, d, seed):
     assert (np.asarray(dk) >= -1e-4).all()  # squared distances
 
 
-# ----------------------------------------------------------- flash attention
-@pytest.mark.parametrize("B,S,H,hd", [(1, 128, 2, 32), (2, 256, 4, 64),
-                                      (1, 512, 1, 128)])
-@pytest.mark.parametrize("causal,window", [(True, 0), (True, 64), (False, 0)])
-def test_flash_attention_sweep(B, S, H, hd, causal, window):
-    rng = np.random.default_rng(S + hd)
-    q = jnp.asarray(rng.normal(size=(B, S, H, hd)).astype(np.float32)) * 0.3
-    k = jnp.asarray(rng.normal(size=(B, S, H, hd)).astype(np.float32)) * 0.3
-    v = jnp.asarray(rng.normal(size=(B, S, H, hd)).astype(np.float32))
-    out = fa_ops.attention(q, k, v, causal=causal, window=window, bq=64, bk=64)
-    exp = fa_ref.attention(q, k, v, causal=causal, window=window)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
-                               rtol=2e-4, atol=2e-4)
-
-
-def test_flash_attention_bf16():
-    rng = np.random.default_rng(0)
-    q = jnp.asarray(rng.normal(size=(2, 128, 2, 64)), jnp.bfloat16) * 0.3
-    k = jnp.asarray(rng.normal(size=(2, 128, 2, 64)), jnp.bfloat16) * 0.3
-    v = jnp.asarray(rng.normal(size=(2, 128, 2, 64)), jnp.bfloat16)
-    out = fa_ops.attention(q, k, v, bq=64, bk=64)
-    exp = fa_ref.attention(q, k, v)
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(exp, np.float32), rtol=5e-2, atol=5e-2)
-
-
-# -------------------------------------------------------------- mamba scan
-@pytest.mark.parametrize("B,S,di,st_", [(1, 32, 8, 4), (2, 64, 16, 8),
-                                        (1, 128, 32, 16)])
-def test_mamba_scan_sweep(B, S, di, st_):
-    rng = np.random.default_rng(S + di)
-    # decays in (0, 1) like exp(dt * A) with A < 0
-    a = jnp.asarray(rng.uniform(0.7, 0.999, size=(B, S, di, st_)).astype(np.float32))
-    b = jnp.asarray(rng.normal(size=(B, S, di, st_)).astype(np.float32)) * 0.1
-    C = jnp.asarray(rng.normal(size=(B, S, st_)).astype(np.float32))
-    h0 = jnp.asarray(rng.normal(size=(B, di, st_)).astype(np.float32)) * 0.1
-    y, h_last = ms_ops.scan(a, b, C, h0, bdi=min(8, di), bs=min(16, S))
-    y_ref, h_ref = ms_ref.scan(a, b, C, h0)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), rtol=1e-4,
-                               atol=1e-4)
-    np.testing.assert_allclose(np.asarray(h_last), np.asarray(h_ref),
-                               rtol=1e-4, atol=1e-4)
-
-
-@settings(max_examples=8, deadline=None)
-@given(B=st.integers(1, 3), nseq=st.integers(1, 6), di=st.integers(1, 4),
-       st_=st.sampled_from([2, 4, 8]), seed=st.integers(0, 2**31))
-def test_mamba_scan_property(B, nseq, di, st_, seed):
-    """Chunked kernel == sequential recurrence for arbitrary chunking."""
-    S = nseq * 8
-    di_ = di * 8
-    rng = np.random.default_rng(seed)
-    a = jnp.asarray(rng.uniform(0.5, 1.0, size=(B, S, di_, st_)).astype(np.float32))
-    b = jnp.asarray(rng.normal(size=(B, S, di_, st_)).astype(np.float32)) * 0.2
-    C = jnp.asarray(rng.normal(size=(B, S, st_)).astype(np.float32))
-    h0 = jnp.zeros((B, di_, st_), jnp.float32)
-    y, h_last = ms_ops.scan(a, b, C, h0, bdi=8, bs=8)
-    y_ref, h_ref = ms_ref.scan(a, b, C, h0)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), rtol=1e-4,
-                               atol=1e-4)
-    np.testing.assert_allclose(np.asarray(h_last), np.asarray(h_ref),
-                               rtol=1e-4, atol=1e-4)
+@pytest.mark.parametrize("n,k,bn,bk,blocks", [
+    # one 128 MB HDFS block of d=3 points (the K-Means cell)
+    (11_184_128, 50, None, None, (65536, 50)),
+    # the coupled cell's 1M-point frame
+    (1_000_000, 50, None, None, (65536, 50)),
+    # fewer points than a block: bn is capped to n padded to a chunk
+    (300, 20, None, None, (4096, 20)),
+    # more centroids than a block holds
+    (1_000_000, 150, None, None, (65536, 64)),
+    # an explicit bn that is no multiple of a chunk is rounded up
+    (1_000_000, 50, 5000, None, (8192, 50)),
+    # an explicit bk above what the kernel unrolls is capped
+    (1_000_000, 300, None, 200, (65536, 128)),
+], ids=["block-cell", "coupled-frame", "small-n", "k150", "bn-rounded",
+        "bk-capped"])
+def test_kmeans_resolve_blocks(n, k, bn, bk, blocks):
+    assert km_ops.resolve_blocks(n, k, bn, bk) == blocks

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 from repro.spans import span
 
+from .draw import choice_distinct
 from .engine import AnalyticsEngine
 
 PAPER_SCENARIOS = {
@@ -62,8 +63,7 @@ def kmeans_fit(engine: AnalyticsEngine, name: str, k: int, *,
     with span("kmeans.init"):
         pts = engine.get(name)
         n, d = pts.shape
-        key = jax.random.key(seed)
-        idx = jax.random.choice(key, n, (k,), replace=False)
+        idx = choice_distinct(jax.random.key(seed), n, k)
         centroids = pts[idx]
 
     cost = jnp.inf

@@ -1,7 +1,7 @@
 """Compile every Pallas kernel for a described TPU v5e chip, at the widths
-the main path runs them, with the blocks ``resolve_blocks`` picks, and the
+the main path runs them, with the blocks ``resolve_blocks`` picks, the
 training cells' train steps, one layer deep, whose attention runs in the
-fused causal kernel.
+fused causal kernel, and K-Means' initial draw over one block of points.
 
 Nothing runs: the TPU compiler lowers each kernel for a chip that is
 described, not attached, and refuses what the chip would refuse (VMEM
@@ -20,6 +20,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro import configs
+from repro.analytics import draw
 from repro.kernels.kmeans import kmeans as km_kernel
 from repro.kernels.kmeans import ops as km_ops
 from repro.models import transformer
@@ -96,6 +97,27 @@ def test_kmeans_ops_wrapper_picks_compiled_kernel(one_chip):
                         _spec((10_240, 3), jnp.float32, one_chip),
                         _spec((5_120, 3), jnp.float32, one_chip))
     _assert_kmeans_results(compiled, 10_240)
+
+
+def _sorts_of(n: int, text: str):
+    return re.findall(rf"^\s*%\S+ = [^\n]*\[{n}\][^\n]* sort\(", text, re.M)
+
+
+def test_kmeans_draw_sorts_no_index_array(one_chip):
+    """K-Means' initial draw of 50 of one 128 MB block's 11,184,128 points:
+    the rank selection counts digits as one-hot products and sorts nothing
+    of the block, where jax's permutation sorts every index once a round
+    (shown at 4,096, where the compile is quick)."""
+    key = _spec((), jax.random.key(0).dtype, one_chip)
+    lower = lambda fn: jax.jit(fn).lower(key).compile().as_text()
+    n, k = 11_184_128, 50
+    text = lower(lambda key: draw.choice_distinct(key, n, k))
+    assert re.search(rf"s32\[{k},{draw.BINS}\]\S* convolution\(", text)
+    assert not _sorts_of(n, text)
+    small = 4_096
+    permuted = lower(lambda key: jax.random.choice(key, small, (k,),
+                                                   replace=False))
+    assert len(_sorts_of(small, permuted)) == draw.shuffle_rounds(small) == 2
 
 
 # the training cells' attention: InternLM2 (16 query heads over 8 KV heads,
